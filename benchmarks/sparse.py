@@ -24,7 +24,6 @@ import numpy as np
 from repro.core.speculative import tree as T
 from repro.kernels import ops as kops
 from repro.kernels.ref import sparse_tree_ref
-from repro.kernels.sparse_tree import sparse_tree_attention
 from repro.models import common as cm
 
 
@@ -75,7 +74,7 @@ def run(width=64, ctx=256, H=32, Hkv=8, hd=128) -> list:
           f"dense-with-mask(ctx+tree)={dense_flops/1e6:.1f}MF "
           f"block-masked={block_flops/1e6:.1f}MF true-sparse={coo_flops/1e6:.1f}MF")
 
-    t_block = _time(lambda: sparse_tree_attention(q, kn, vn, mask))
+    t_block = _time(lambda: kops.sparse_tree_attention(q, kn, vn, mask))
     t_densemask = _time(lambda: jax.jit(sparse_tree_ref)(q, kn, vn,
                                                          jnp.ones_like(mask) & mask))
     t_naive = _time(lambda: _naive_coo(q, kn, vn, mask), reps=1)

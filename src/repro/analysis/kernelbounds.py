@@ -17,6 +17,9 @@ module proves:
   points that revisit one tile form a contiguous run in lexicographic
   grid order (the sequential minor-most axis on TPU — a non-contiguous
   revisit would clobber the online-softmax accumulator);
+* **tiling** — each of a block's two minor dims equals the operand's or
+  is a multiple of the (8, 128) TPU tile (the Pallas TPU lowering's
+  rule, which interpret mode never checks);
 * **page domain** (paged wrapper) — the table-walk can only address
   pages reserved in that sequence's block-table row or the trailing
   trash page ``P - 1``, never another sequence's pages via an
@@ -97,17 +100,24 @@ class KernelSpec:
 # --------------------------------------------------------------------------
 # config matrix — dense + paged + sparse, page-size / W / depth sweeps
 # --------------------------------------------------------------------------
+def _heads(Hq, Hkv):
+    """Mirror of ``tree_attention._head_group``: kv heads per grid step."""
+    hg = 8 if Hkv % 8 == 0 else Hkv
+    return dict(G=Hq // Hkv, hg=hg, nh=Hkv // hg)
+
+
 def _dense_cfg(B, W, Hq, Hkv, hd, S, block_s) -> Config:
-    G = Hq // Hkv
+    h = _heads(Hq, Hkv)
+    G = h["G"]
     bs = min(block_s, max(S, 1))
     pad = (-S) % bs
     nblocks = (S + pad) // bs
     Sp = S + pad
-    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, S=S, G=G, bs=bs,
-               pad=pad, nblocks=nblocks, block_s=block_s)
+    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, S=S, bs=bs, pad=pad,
+               nblocks=nblocks, block_s=block_s, **h)
     ops = [(B, Hkv, G * W, hd), (B, Sp, Hkv, hd), (B, Sp, Hkv, hd),
-           (B, W, Hkv, hd), (B, W, Hkv, hd), (B, Sp), (B, W), (B, W),
-           (W, W)]
+           (B, W, Hkv, hd), (B, W, Hkv, hd), (B, nblocks, 1, bs),
+           (B, G * W, 1), (B, G * W, 1), (G * W, W)]
     return Config(
         desc=f"dense B={B} W={W} Hq={Hq} Hkv={Hkv} hd={hd} S={S} "
              f"block_s={block_s} (bs={bs} pad={pad} nblocks={nblocks})",
@@ -116,18 +126,18 @@ def _dense_cfg(B, W, Hq, Hkv, hd, S, block_s) -> Config:
 
 def _paged_cfg(B, W, Hq, Hkv, hd, ps, P, tables) -> Config:
     # operand order mirrors the wrapper: q, pool_k, pool_v, scale_k,
-    # scale_v, k_new, v_new, key_pos, q_pos, lo, tree_mask.  The (P, Hkv)
-    # dequant scales walk the SAME table-driven index map as the pools, so
-    # they join the page-domain check (a scale fetched from another
-    # sequence's page would dequantize with the wrong amax).
-    G = Hq // Hkv
+    # scale_v, k_new, v_new, key_pos, q_pos, lo, tree_mask.  The
+    # (P, Hkv, 1) dequant scales walk the SAME table-driven index map as
+    # the pools, so they join the page-domain check (a scale fetched from
+    # another sequence's page would dequantize with the wrong amax).
+    h = _heads(Hq, Hkv)
+    G = h["G"]
     maxp = len(tables[0])
-    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, G=G, P=P, ps=ps,
-               maxp=maxp)
+    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, P=P, ps=ps, maxp=maxp, **h)
     ops = [(B, Hkv, G * W, hd), (P, ps, Hkv, hd), (P, ps, Hkv, hd),
-           (P, Hkv), (P, Hkv),
-           (B, W, Hkv, hd), (B, W, Hkv, hd), (B, maxp * ps), (B, W),
-           (B, W), (W, W)]
+           (P, Hkv, 1), (P, Hkv, 1),
+           (B, W, Hkv, hd), (B, W, Hkv, hd), (B, maxp, 1, ps),
+           (B, G * W, 1), (B, G * W, 1), (G * W, W)]
     reserved = [sum(1 for v in row if v >= 0) for row in tables]
     return Config(
         desc=f"paged B={B} W={W} Hq={Hq} Hkv={Hkv} hd={hd} ps={ps} "
@@ -138,13 +148,14 @@ def _paged_cfg(B, W, Hq, Hkv, hd, ps, P, tables) -> Config:
 def _paged_cache_cfg(B, W, Hq, Hkv, hd, ps, P, tables) -> Config:
     """``paged_cache_attention`` (split verify path): the paged walk minus
     the tree operands — q, pool_k, pool_v, scale_k, scale_v, key_pos,
-    q_pos, lo — with a (B, Hkv, maxp) grid (no trailing tree block)."""
-    G = Hq // Hkv
+    q_pos, lo — with a (B, nh, maxp) grid (no trailing tree block)."""
+    h = _heads(Hq, Hkv)
+    G = h["G"]
     maxp = len(tables[0])
-    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, G=G, P=P, ps=ps,
-               maxp=maxp)
+    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, P=P, ps=ps, maxp=maxp, **h)
     ops = [(B, Hkv, G * W, hd), (P, ps, Hkv, hd), (P, ps, Hkv, hd),
-           (P, Hkv), (P, Hkv), (B, maxp * ps), (B, W), (B, W)]
+           (P, Hkv, 1), (P, Hkv, 1), (B, maxp, 1, ps), (B, G * W, 1),
+           (B, G * W, 1)]
     reserved = [sum(1 for v in row if v >= 0) for row in tables]
     return Config(
         desc=f"paged-cache B={B} W={W} Hq={Hq} Hkv={Hkv} hd={hd} ps={ps} "
@@ -153,10 +164,11 @@ def _paged_cache_cfg(B, W, Hq, Hkv, hd, ps, P, tables) -> Config:
 
 
 def _sparse_cfg(B, W, Hq, Hkv, hd) -> Config:
-    G = Hq // Hkv
-    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, G=G)
+    h = _heads(Hq, Hkv)
+    G = h["G"]
+    env = dict(B=B, W=W, Hq=Hq, Hkv=Hkv, hd=hd, **h)
     ops = [(B, Hkv, G * W, hd), (B, W, Hkv, hd), (B, W, Hkv, hd),
-           (W, W)]
+           (G * W, W)]
     return Config(desc=f"sparse B={B} W={W} Hq={Hq} Hkv={Hkv} hd={hd}",
                   env=env, operands=ops)
 
@@ -168,6 +180,7 @@ CONFIGS: Dict[str, List[Config]] = {
         _dense_cfg(3, 4, 8, 4, 16, 3, 512),    # S < block_s (bs=S)
         _dense_cfg(2, 8, 8, 2, 8, 64, 16),     # deep tree, 4 KV blocks
         _dense_cfg(1, 4, 4, 4, 8, 1, 512),     # single-slot cache
+        _dense_cfg(2, 2, 16, 16, 8, 24, 8),    # two 8-head groups
     ],
     "paged_tree_attention": [
         _paged_cfg(2, 4, 4, 2, 8, 8, 6,
@@ -177,6 +190,8 @@ CONFIGS: Dict[str, List[Config]] = {
                    [[0, 1, 2, 3, 4, 5], [6, 7, -1, -1, -1, -1],
                     [-1] * 6]),                          # full/partial/0
         _paged_cfg(2, 8, 8, 8, 8, 16, 4, [[0], [2]]),    # maxp=1 edge
+        _paged_cfg(2, 2, 16, 16, 8, 4, 5,
+                   [[0, 1, -1], [2, -1, -1]]),           # two head groups
     ],
     "paged_cache_attention": [
         _paged_cache_cfg(2, 4, 4, 2, 8, 8, 6,
@@ -186,11 +201,13 @@ CONFIGS: Dict[str, List[Config]] = {
                          [[0, 1, 2, 3, 4, 5], [6, 7, -1, -1, -1, -1],
                           [-1] * 6]),
         _paged_cache_cfg(2, 8, 8, 8, 8, 16, 4, [[0], [2]]),
+        _paged_cache_cfg(2, 2, 16, 16, 8, 4, 5, [[0, 1, -1], [2, -1, -1]]),
     ],
     "sparse_tree_attention": [
         _sparse_cfg(2, 4, 4, 2, 8),
         _sparse_cfg(1, 2, 2, 2, 4),
         _sparse_cfg(3, 8, 8, 4, 16),
+        _sparse_cfg(2, 2, 32, 16, 8),
     ],
     # the W x W tree half of the split verify path: same operands as
     # sparse_tree_attention, packed-(hd + 2) partials output
@@ -198,6 +215,7 @@ CONFIGS: Dict[str, List[Config]] = {
         _sparse_cfg(2, 4, 4, 2, 8),
         _sparse_cfg(1, 2, 2, 2, 4),
         _sparse_cfg(3, 8, 8, 4, 16),
+        _sparse_cfg(2, 2, 32, 16, 8),
     ],
 }
 
@@ -312,6 +330,16 @@ def _evaluate(expr, env: Dict) -> object:
     return eval(code, genv)          # noqa: S307 — our own parsed source
 
 
+def _untiled_dims(blk: Tuple, opshape: Tuple) -> List[int]:
+    """Minor block dims that Mosaic refuses: neither the operand's full
+    extent nor a multiple of the (8, 128) tile."""
+    bad = []
+    for d, tile in zip(range(len(blk) - 2, len(blk)), (8, 128)):
+        if d >= 0 and blk[d] != opshape[d] and blk[d] % tile:
+            bad.append(d)
+    return bad
+
+
 def _as_tuple(v) -> Tuple:
     return tuple(v) if isinstance(v, tuple) else (v,)
 
@@ -378,6 +406,14 @@ def check_spec(spec: KernelSpec, cfg: Config) -> List[Tuple[int, str]]:
                          f"block shape {blk} has rank {len(blk)} but "
                          f"the operand is rank {len(opshape)} "
                          f"{opshape}"))
+            return None
+        bad = _untiled_dims(blk, opshape)
+        if bad:
+            errs.append((line, f"`{spec.name}` [{cfg.desc}]: {what} "
+                         f"block {blk} over operand {opshape} breaks "
+                         f"the TPU tiling rule in dims {bad}: each of "
+                         f"the two minor block dims must equal the "
+                         f"operand's or be a multiple of (8, 128)"))
             return None
         if not callable(imap):
             errs.append((line, f"`{spec.name}` [{cfg.desc}]: {what} "

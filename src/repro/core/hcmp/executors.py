@@ -41,12 +41,15 @@ host locks, so reprolint's R4 has nothing to guard):
     counters — it is only ever entered from the engine's single-threaded
     ``sched_step``/``generate`` callers.
 
-On this CPU container the two executors are the two XLA host devices
-requested via ``--xla_force_host_platform_device_count=2``
-(``ensure_host_devices``); with one device the runner degrades to a
-serial schedule on device 0 — still bit-identical, just no overlap.
-When an accelerator is attached the same placement logic lands verify on
-the accelerator and draft on host CPU (Dovetail's split).
+Placement (``executor_pair``): on the CPU backend the two executors are
+two XLA host devices requested with
+``--xla_force_host_platform_device_count=2`` (``ensure_host_devices``),
+so the overlap is real there.  On a TPU host both executors are the first
+chip: a second chip is never taken without being asked, so one chip runs
+draft and verify serially on its own stream — still bit-identical to the
+inline scan, with no overlap.  Whether the draft should move to the host
+CPU or to another chip is for ARCA to measure, not for this module to
+assume.
 """
 from __future__ import annotations
 
@@ -63,24 +66,38 @@ from repro.runtime.cache import capacity_left
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 
+def _cpu_platform() -> bool:
+    """Whether JAX is pinned to the CPU platform, read from the platform
+    setting alone (asking the backend would initialize it, after which
+    the host device count can no longer change)."""
+    platforms = jax.config.jax_platforms or os.environ.get(
+        "JAX_PLATFORMS", "")
+    return platforms.split(",")[0].strip() == "cpu"
+
+
 def ensure_host_devices(n: int = 2) -> int:
-    """Best-effort request for ``n`` XLA host CPU devices.
+    """Best-effort request for ``n`` XLA host CPU devices when JAX runs on
+    the CPU platform; on any other platform it adds nothing.
 
     Only effective BEFORE the jax backend initializes (serve.py calls it
-    first thing in ``main``); afterwards it is a no-op probe.  Returns
+    before building the model); afterwards it is a no-op probe.  Returns
     the number of devices actually visible — callers must tolerate 1
     (the runner then runs both executors on device 0, serially)."""
-    if _DEVICE_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    if _cpu_platform() and _DEVICE_FLAG not in os.environ.get("XLA_FLAGS",
+                                                               ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + f" {_DEVICE_FLAG}={n}").strip()
     return len(jax.devices())
 
 
 def executor_pair():
-    """(verify_device, draft_device): the first two local devices, or the
-    single device twice (serial fallback)."""
+    """(verify_device, draft_device): the first two host devices on the
+    CPU backend; device 0 twice otherwise (one device, or accelerators,
+    where a second chip is never taken without being asked)."""
     devs = jax.devices()
-    return devs[0], devs[1] if len(devs) > 1 else devs[0]
+    if jax.default_backend() == "cpu" and len(devs) > 1:
+        return devs[0], devs[1]
+    return devs[0], devs[0]
 
 
 class HcmpOverlapRunner:

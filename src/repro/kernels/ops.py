@@ -1,16 +1,23 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; TPU is the
-compile target).  Set ``repro.kernels.ops.INTERPRET = False`` on real TPU.
+Interpret mode follows the backend the caller is traced for: the kernels
+compile through Mosaic on a TPU and run in the Pallas interpreter on the
+CPU (the test backend).  Nothing else selects it, so a chip run never
+interprets by accident.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import sparse_tree as _sparse
 from repro.kernels import tree_attention as _tree
 
-INTERPRET = True
+
+def _interpret() -> bool:
+    """True on the CPU backend, False on a TPU (and anywhere else, where
+    the Mosaic lowering then fails loudly instead of interpreting)."""
+    return jax.default_backend() == "cpu"
 
 
 def tree_attention(q, ck, cv, k_new, v_new, key_pos, pos, tree_depth,
@@ -29,7 +36,7 @@ def tree_attention(q, ck, cv, k_new, v_new, key_pos, pos, tree_depth,
         lo = q_pos - window
     else:
         lo = jnp.full_like(q_pos, -1)
-    kwargs = {"interpret": INTERPRET}
+    kwargs = {"interpret": _interpret()}
     if block_s:
         kwargs["block_s"] = block_s
     return _tree.tree_attention(q, ck, cv, k_new, v_new, key_pos_b, q_pos,
@@ -65,7 +72,7 @@ def paged_tree_attention(q, pool_k, pool_v, k_new, v_new, block_table,
     sk, sv = _pool_scales(pool_k, scale_k, scale_v)
     return _tree.paged_tree_attention(q, pool_k, pool_v, sk, sv, k_new,
                                       v_new, block_table, key_pos, q_pos,
-                                      lo, tree_mask, interpret=INTERPRET)
+                                      lo, tree_mask, interpret=_interpret())
 
 
 def paged_cache_attention(q, pool_k, pool_v, block_table, key_pos, pos,
@@ -81,7 +88,7 @@ def paged_cache_attention(q, pool_k, pool_v, block_table, key_pos, pos,
     sk, sv = _pool_scales(pool_k, scale_k, scale_v)
     return _tree.paged_cache_attention(q, pool_k, pool_v, sk, sv,
                                        block_table, key_pos, q_pos, lo,
-                                       interpret=INTERPRET)
+                                       interpret=_interpret())
 
 
 def decode_attention(q, ck, cv, k_new, v_new, key_pos, pos, *, window=0):
@@ -91,21 +98,17 @@ def decode_attention(q, ck, cv, k_new, v_new, key_pos, pos, *, window=0):
                           jnp.ones((1, 1), bool), window=window)
 
 
-def sparse_tree_attention(q, k_new, v_new, tree_mask, *, backend="pallas",
-                          interpret=None):
+def sparse_tree_attention(q, k_new, v_new, tree_mask, *, backend="pallas"):
     """W×W tree-correlation attention (sparse part only).
 
     Dispatches per ``backend`` like ``attn_verify`` does — ``"ref"`` runs
-    the jnp oracle, ``"pallas"`` the block-masked kernel — instead of
-    hardcoding the kernel's interpret default; ``interpret=None`` resolves
-    to the module-level ``INTERPRET`` platform switch.
+    the jnp oracle, ``"pallas"`` the block-masked kernel.
     """
     if backend == "ref":
         from repro.kernels import ref as _ref
         return _ref.sparse_tree_ref(q, k_new, v_new, tree_mask)
-    return _sparse.sparse_tree_attention(
-        q, k_new, v_new, tree_mask,
-        interpret=INTERPRET if interpret is None else interpret)
+    return _sparse.sparse_tree_attention(q, k_new, v_new, tree_mask,
+                                         interpret=_interpret())
 
 
 def sparse_tree_attention_partial(q, k_new, v_new, tree_mask):
@@ -113,4 +116,4 @@ def sparse_tree_attention_partial(q, k_new, v_new, tree_mask):
     merge partials of the W×W masked tree attention (merged with the
     ``paged_cache_attention`` page walk by the caller)."""
     return _sparse.sparse_tree_attention_partial(q, k_new, v_new, tree_mask,
-                                                 interpret=INTERPRET)
+                                                 interpret=_interpret())

@@ -6,13 +6,22 @@ W fresh tree KVs under the ancestor mask (sparse part, VMEM-resident), with
 a single online-softmax accumulator carried across the grid — the kernel
 form of the paper's Eq.-1 online-softmax merge.
 
-Layout: one (batch, kv-head) pair per grid row; queries are grouped
-(G = Hq/Hkv rows per kv head) so the score matmul is (G*W, hd) x (hd, BS) —
-MXU-aligned when BS and hd are multiples of 128 and G*W of 8.
+Layout: one (batch, kv-head group) pair per grid row.  A K/V block carries
+``hg`` whole kv heads (``_head_group``), so the block's two minor dims are
+``(hg, hd)`` — the whole ``(Hkv, hd)`` of the cache, or 8 heads of it —
+which is what the TPU block-tiling rule accepts; the body loops over the
+group's heads.  Queries are grouped (G = Hq/Hkv rows per kv head) so each
+head's score matmul is (G*W, hd) x (hd, BS).
 
-Grid: (B, Hkv, nblocks+1); the last block handles the tree part and the
-normalization + writeback.  Scratch (o, m, l) persists across the KV-block
-axis (sequential minor-most grid dimension on TPU).
+The small per-row operands ride in layouts whose two minor block dims equal
+the array's: key positions as ``(B, nblocks, 1, BS)`` rows, query positions
+and window bounds as ``(B, G*W, 1)`` columns already expanded over the G
+query groups, the tree mask as ``(G*W, W)`` int32, and the int8 dequant
+scales as ``(n_pages + 1, Hkv, 1)``.
+
+Grid: (B, Hkv // hg, nblocks+1); the last block handles the tree part and
+the normalization + writeback.  Scratch (o, m, l) persists across the
+KV-block axis (sequential minor-most grid dimension on TPU).
 
 Paged variant (``paged_tree_attention``): the KV blocks live in a SHARED
 page pool ``(n_pages + 1, page_size, Hkv, hd)`` instead of per-sequence
@@ -20,8 +29,11 @@ rows.  The grid's KV axis loops over a sequence's *logical* pages and the
 block table rides in as a scalar-prefetch argument, so the index map DMAs
 physical page ``table[b, i]`` for grid step ``i`` — unreserved entries are
 pre-clamped to the trailing trash page, whose slots carry ``key_pos == -1``
-and mask to zero weight.  The kernel body is byte-for-byte the dense one;
-only the BlockSpec index maps change.
+and mask to zero weight.  The kernel body is the dense one; only the
+BlockSpec index maps change.
+
+Every wrapper takes ``interpret`` with no default: ``kernels/ops.py``
+resolves it from the backend (interpreted on the CPU, compiled on a TPU).
 """
 from __future__ import annotations
 
@@ -36,10 +48,82 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_S = 512
 
 
+def _head_group(Hkv):
+    """KV heads per grid step: all of them, or 8 when Hkv is a multiple of
+    8.  Either satisfies the tiling rule on the block's (heads, hd) minor
+    dims, and groups of 8 keep a 32-head cache's double-buffered K/V
+    blocks inside the scoped VMEM budget at block_s=512."""
+    return 8 if Hkv % 8 == 0 else Hkv
+
+
+def _row_operands(key_pos, q_pos, lo, G, bs):
+    """Per-row operands in tiling-legal layouts: key positions (B, S) ->
+    (B, S // bs, 1, bs); query positions and window bounds (B, W) ->
+    (B, G*W, 1) columns, row g*W + w carrying node w (the grouped query
+    row order)."""
+    B, S = key_pos.shape
+    kpos = key_pos.astype(jnp.int32).reshape(B, S // bs, 1, bs)
+    qcol = jnp.tile(q_pos.astype(jnp.int32), (1, G))[..., None]
+    locol = jnp.tile(lo.astype(jnp.int32), (1, G))[..., None]
+    return kpos, qcol, locol
+
+
+def _group_queries(q, Hkv):
+    """(B, W, Hq, hd) -> (B, Hkv, G*W, hd), row g*W + w."""
+    B, W, Hq, hd = q.shape
+    G = Hq // Hkv
+    return q.reshape(B, W, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(
+        B, Hkv, G * W, hd)
+
+
+def _ungroup(out, W):
+    """(B, Hkv, G*W, hd) -> (B, W, Hq, hd)."""
+    B, Hkv, GW, hd = out.shape
+    G = GW // W
+    return out.reshape(B, Hkv, G, W, hd).transpose(0, 3, 1, 2, 4).reshape(
+        B, W, Hkv * G, hd)
+
+
+def _group_mask(tree_mask, G):
+    """(W, W) bool ancestor mask -> (G*W, W) int32, one copy per group."""
+    return jnp.tile(tree_mask.astype(jnp.int32), (G, 1))
+
+
+def _scores(q, k):
+    """(R, hd) x (T, hd) -> (R, T), contracting hd without a transpose."""
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _pv(p, v):
+    return jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _cache_ok(kpos_ref, qpos_ref, lo_ref):
+    """(G*W, BS) validity of this KV block's slots for every query row."""
+    kpos = kpos_ref[0, 0]                          # (1, BS)
+    qpos = qpos_ref[0]                             # (G*W, 1)
+    lo = lo_ref[0]
+    return (kpos >= 0) & (kpos <= qpos) & (kpos > lo)
+
+
+def _kv_head(ref, h, s_ref):
+    """Head ``h`` of a (1, T, hg, hd) K/V block as (T, hd) float32,
+    dequantized by the page's (layer, head) scale when one is given."""
+    x = ref[0, :, h, :].astype(jnp.float32)
+    if s_ref is not None:
+        x = x * s_ref[0, h, 0]
+    return x
+
+
 def _kernel(q_ref, ck_ref, cv_ref, kn_ref, vn_ref, kpos_ref, qpos_ref,
             lo_ref, mask_ref, o_ref, o_acc, m_acc, l_acc, *, nblocks, scale,
             sk_ref=None, sv_ref=None):
     i = pl.program_id(2)
+    hg = q_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
@@ -47,61 +131,55 @@ def _kernel(q_ref, ck_ref, cv_ref, kn_ref, vn_ref, kpos_ref, qpos_ref,
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (GW, hd)
-    GW = q.shape[0]
-    W = qpos_ref.shape[1]
-    G = GW // W
-
-    def online_update(s, v, valid):
-        """s: (GW, T) scores; v: (T, hd); valid: (GW, T) bool."""
-        s = jnp.where(valid, s * scale, NEG_INF)
-        m_new = jnp.maximum(m_acc[...], jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_acc[...] - m_new)
-        l_acc[...] = l_acc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[...] = o_acc[...] * corr + p @ v
-        m_acc[...] = m_new
+    def online_update(h, k, v, valid):
+        """k, v: (T, hd); valid: (G*W, T) bool."""
+        q = q_ref[0, h].astype(jnp.float32)        # (G*W, hd)
+        s = jnp.where(valid, _scores(q, k) * scale, NEG_INF)
+        m_prev = m_acc[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_acc[h] = l_acc[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        o_acc[h] = o_acc[h] * corr + _pv(p, v)
+        m_acc[h] = m_new
 
     @pl.when(i < nblocks)
     def _cache_block():
-        k = ck_ref[0, :, 0].astype(jnp.float32)    # (BS, hd)
-        v = cv_ref[0, :, 0].astype(jnp.float32)
-        if sk_ref is not None:
-            # fused dequant: this page's per-(layer, head) scale arrived on
-            # the same scalar-prefetch walk as the page index (1.0 for
-            # float pools, so the multiply is exact there)
-            k = k * sk_ref[0, 0]
-            v = v * sv_ref[0, 0]
-        kpos = kpos_ref[0]                         # (BS,) this sequence's row
-        qpos = qpos_ref[0]                         # (W,)
-        lo = lo_ref[0]
-        ok = ((kpos[None, :] >= 0)
-              & (kpos[None, :] <= qpos[:, None])
-              & (kpos[None, :] > lo[:, None]))     # (W, BS)
-        ok = jnp.broadcast_to(ok[None], (G, W, ok.shape[1])).reshape(GW, -1)
-        online_update(q @ k.T, v, ok)
+        # fused dequant: the page's per-(layer, head) scale rides the same
+        # table-driven index map as the page (1.0 for float pools, so the
+        # multiply is exact there)
+        ok = _cache_ok(kpos_ref, qpos_ref, lo_ref)
+        for h in range(hg):
+            online_update(h, _kv_head(ck_ref, h, sk_ref),
+                          _kv_head(cv_ref, h, sv_ref), ok)
 
     @pl.when(i == nblocks)
     def _tree_block():
-        k = kn_ref[0, :, 0].astype(jnp.float32)    # (W, hd)
-        v = vn_ref[0, :, 0].astype(jnp.float32)
-        tm = mask_ref[...]                         # (W, W) bool
-        ok = jnp.broadcast_to(tm[None], (G,) + tm.shape).reshape(GW, -1)
-        online_update(q @ k.T, v, ok)
-        l_safe = jnp.maximum(l_acc[...], 1e-30)
-        o_ref[0, 0] = (o_acc[...] / l_safe).astype(o_ref.dtype)
+        ok = mask_ref[...] != 0                    # (G*W, W)
+        for h in range(hg):
+            online_update(h, _kv_head(kn_ref, h, None),
+                          _kv_head(vn_ref, h, None), ok)
+            l_safe = jnp.maximum(l_acc[h], 1e-30)
+            o_ref[0, h] = (o_acc[h] / l_safe).astype(o_ref.dtype)
+
+
+def _scratch(hg, GW, hd):
+    return [pltpu.VMEM((hg, GW, hd), jnp.float32),   # o accumulator
+            pltpu.VMEM((hg, GW, 1), jnp.float32),    # running max m
+            pltpu.VMEM((hg, GW, 1), jnp.float32)]    # running sum l
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def tree_attention(q, ck, cv, k_new, v_new, key_pos, q_pos, lo, tree_mask,
-                   *, block_s=DEFAULT_BLOCK_S, interpret=True):
+                   *, block_s=DEFAULT_BLOCK_S, interpret):
     """See ref.tree_attention_ref for semantics.  q: (B, W, Hq, hd);
     key_pos: (B, S); q_pos/lo: (B, W) — per-sequence position rows (batched
     speculative decoding leaves each sequence at its own absolute position)."""
     B, W, Hq, hd = q.shape
     S, Hkv = ck.shape[1], ck.shape[2]
     G = Hq // Hkv
+    hg = _head_group(Hkv)
+    nh = Hkv // hg
 
     # pad cache length to a block multiple; padded slots get key_pos = -1
     bs = min(block_s, max(S, 1))
@@ -111,49 +189,38 @@ def tree_attention(q, ck, cv, k_new, v_new, key_pos, q_pos, lo, tree_mask,
         cv = jnp.pad(cv, ((0, 0), (0, pad), (0, 0), (0, 0)))
         key_pos = jnp.pad(key_pos, ((0, 0), (0, pad)), constant_values=-1)
     nblocks = (S + pad) // bs
+    kpos, qcol, locol = _row_operands(key_pos, q_pos, lo, G, bs)
 
-    # regroup queries: (B, Hkv, G*W, hd)
-    qg = q.reshape(B, W, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(
-        B, Hkv, G * W, hd)
-    # cache: (B, S, Hkv, hd) kept as-is; block over S
-    kn = k_new                                      # (B, W, Hkv, hd)
-
-    grid = (B, Hkv, nblocks + 1)
     out = pl.pallas_call(
         functools.partial(_kernel, nblocks=nblocks, scale=hd ** -0.5),
-        grid=grid,
+        grid=(B, nh, nblocks + 1),
         in_specs=[
-            pl.BlockSpec((1, 1, G * W, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
+            pl.BlockSpec((1, hg, G * W, hd), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, bs, hg, hd),
                          lambda b, h, i, _n=nblocks: (b, jnp.minimum(i, _n - 1), h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
+            pl.BlockSpec((1, bs, hg, hd),
                          lambda b, h, i, _n=nblocks: (b, jnp.minimum(i, _n - 1), h, 0)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, i: (b, 0, h, 0)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, i: (b, 0, h, 0)),
-            pl.BlockSpec((1, bs),
-                         lambda b, h, i, _n=nblocks: (b, jnp.minimum(i, _n - 1))),
-            pl.BlockSpec((1, W), lambda b, h, i: (b, 0)),
-            pl.BlockSpec((1, W), lambda b, h, i: (b, 0)),
-            pl.BlockSpec((W, W), lambda b, h, i: (0, 0)),
+            pl.BlockSpec((1, W, hg, hd), lambda b, h, i: (b, 0, h, 0)),
+            pl.BlockSpec((1, W, hg, hd), lambda b, h, i: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, 1, bs),
+                         lambda b, h, i, _n=nblocks: (b, jnp.minimum(i, _n - 1), 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i: (b, 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i: (b, 0, 0)),
+            pl.BlockSpec((G * W, W), lambda b, h, i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G * W, hd), lambda b, h, i: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hg, G * W, hd), lambda b, h, i: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G * W, hd), jnp.float32),   # o accumulator
-            pltpu.VMEM((G * W, 1), jnp.float32),    # running max m
-            pltpu.VMEM((G * W, 1), jnp.float32),    # running sum l
-        ],
+        scratch_shapes=_scratch(hg, G * W, hd),
         interpret=interpret,
-    )(qg, ck, cv, kn, v_new, key_pos, q_pos, lo, tree_mask)
-    # regroup back: (B, W, Hq, hd)
-    return out.reshape(B, Hkv, G, W, hd).transpose(0, 3, 1, 2, 4).reshape(
-        B, W, Hq, hd)
+    )(_group_queries(q, Hkv), ck, cv, k_new, v_new, kpos, qcol, locol,
+      _group_mask(tree_mask, G))
+    return _ungroup(out, W)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_tree_attention(q, pool_k, pool_v, scale_k, scale_v, k_new, v_new,
                          block_table, key_pos, q_pos, lo, tree_mask, *,
-                         interpret=True):
+                         interpret):
     """Paged tree-verification attention: the KV-block grid axis walks a
     sequence's block table instead of a dense row.
 
@@ -164,52 +231,48 @@ def paged_tree_attention(q, pool_k, pool_v, scale_k, scale_v, k_new, v_new,
     unreserved); key_pos: (B, max_pages * ps); q_pos/lo: (B, W).
     One KV "block" is one page (block_s == page_size): grid step i of row b
     fetches physical page ``table[b, i]`` via scalar prefetch, and the
-    page's (1, 1) scale block rides the same table-driven index map.
+    page's scale block rides the same table-driven index map.
     """
     B, W, Hq, hd = q.shape
     P, ps, Hkv = pool_k.shape[0], pool_k.shape[1], pool_k.shape[2]
     maxp = block_table.shape[1]
     G = Hq // Hkv
+    hg = _head_group(Hkv)
+    nh = Hkv // hg
     # unreserved logical pages fetch the trash page; their slots are
     # key_pos == -1, so the validity mask zeroes them
     tbl = jnp.where(block_table < 0, P - 1, block_table).astype(jnp.int32)
-
-    qg = q.reshape(B, W, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(
-        B, Hkv, G * W, hd)
+    kpos, qcol, locol = _row_operands(key_pos, q_pos, lo, G, ps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv, maxp + 1),
+        grid=(B, nh, maxp + 1),
         in_specs=[
-            pl.BlockSpec((1, 1, G * W, hd), lambda b, h, i, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
+            pl.BlockSpec((1, hg, G * W, hd), lambda b, h, i, t: (b, h, 0, 0)),
+            pl.BlockSpec((1, ps, hg, hd),
                          lambda b, h, i, t, _n=maxp:
                          (t[b, jnp.minimum(i, _n - 1)], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
+            pl.BlockSpec((1, ps, hg, hd),
                          lambda b, h, i, t, _n=maxp:
                          (t[b, jnp.minimum(i, _n - 1)], 0, h, 0)),
-            pl.BlockSpec((1, 1),
+            pl.BlockSpec((1, hg, 1),
                          lambda b, h, i, t, _n=maxp:
-                         (t[b, jnp.minimum(i, _n - 1)], h)),
-            pl.BlockSpec((1, 1),
+                         (t[b, jnp.minimum(i, _n - 1)], h, 0)),
+            pl.BlockSpec((1, hg, 1),
                          lambda b, h, i, t, _n=maxp:
-                         (t[b, jnp.minimum(i, _n - 1)], h)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, i, t: (b, 0, h, 0)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, i, t: (b, 0, h, 0)),
-            pl.BlockSpec((1, ps),
+                         (t[b, jnp.minimum(i, _n - 1)], h, 0)),
+            pl.BlockSpec((1, W, hg, hd), lambda b, h, i, t: (b, 0, h, 0)),
+            pl.BlockSpec((1, W, hg, hd), lambda b, h, i, t: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, 1, ps),
                          lambda b, h, i, t, _n=maxp:
-                         (b, jnp.minimum(i, _n - 1))),
-            pl.BlockSpec((1, W), lambda b, h, i, t: (b, 0)),
-            pl.BlockSpec((1, W), lambda b, h, i, t: (b, 0)),
-            pl.BlockSpec((W, W), lambda b, h, i, t: (0, 0)),
+                         (b, jnp.minimum(i, _n - 1), 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i, t: (b, 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i, t: (b, 0, 0)),
+            pl.BlockSpec((G * W, W), lambda b, h, i, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G * W, hd),
+        out_specs=pl.BlockSpec((1, hg, G * W, hd),
                                lambda b, h, i, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G * W, hd), jnp.float32),
-            pltpu.VMEM((G * W, 1), jnp.float32),
-            pltpu.VMEM((G * W, 1), jnp.float32),
-        ],
+        scratch_shapes=_scratch(hg, G * W, hd),
     )
 
     def kernel(tbl_ref, q_ref, ck_ref, cv_ref, sk_ref, sv_ref, kn_ref,
@@ -227,24 +290,25 @@ def paged_tree_attention(q, pool_k, pool_v, scale_k, scale_v, k_new, v_new,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         interpret=interpret,
-    )(tbl, qg, pool_k, pool_v, scale_k.astype(jnp.float32),
-      scale_v.astype(jnp.float32), k_new, v_new, key_pos, q_pos,
-      lo, tree_mask)
-    return out.reshape(B, Hkv, G, W, hd).transpose(0, 3, 1, 2, 4).reshape(
-        B, W, Hq, hd)
+    )(tbl, _group_queries(q, Hkv), pool_k, pool_v,
+      scale_k.astype(jnp.float32)[..., None],
+      scale_v.astype(jnp.float32)[..., None], k_new, v_new, kpos, qcol,
+      locol, _group_mask(tree_mask, G))
+    return _ungroup(out, W)
 
 
 def _cache_partial_kernel(q_ref, ck_ref, cv_ref, sk_ref, sv_ref, kpos_ref,
                           qpos_ref, lo_ref, o_ref, o_acc, m_acc, l_acc, *,
                           nblocks, scale):
     """Cache-only half of the verify attention, emitting UNNORMALIZED
-    online-softmax partials packed into one (G*W, hd + 2) block — o in
-    [:, :hd], running max m at [:, hd], sum l at [:, hd + 1].  Packing into
-    a single output keeps the wrapper a one-``pallas_call``/one-BlockSpec
-    shape the R8 bounds extractor can verify; the wrapper unpacks to the
-    ``cm.merge_partials`` layout so the sparse tree half (or a sequence
-    shard) merges with the usual Eq.-1 rule."""
+    online-softmax partials packed into one (G*W, hd + 2) block per head —
+    o in [:, :hd], running max m at [:, hd], sum l at [:, hd + 1].  Packing
+    into a single output keeps the wrapper a one-``pallas_call``/
+    one-BlockSpec shape the R8 bounds extractor can verify; the wrapper
+    unpacks to the ``cm.merge_partials`` layout so the sparse tree half (or
+    a sequence shard) merges with the usual Eq.-1 rule."""
     i = pl.program_id(2)
+    hg = q_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
@@ -252,44 +316,38 @@ def _cache_partial_kernel(q_ref, ck_ref, cv_ref, sk_ref, sv_ref, kpos_ref,
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (GW, hd)
-    GW = q.shape[0]
-    W = qpos_ref.shape[1]
-    G = GW // W
-    k = ck_ref[0, :, 0].astype(jnp.float32) * sk_ref[0, 0]
-    v = cv_ref[0, :, 0].astype(jnp.float32) * sv_ref[0, 0]
-    kpos = kpos_ref[0]
-    qpos = qpos_ref[0]
-    lo = lo_ref[0]
-    ok = ((kpos[None, :] >= 0)
-          & (kpos[None, :] <= qpos[:, None])
-          & (kpos[None, :] > lo[:, None]))         # (W, ps)
-    ok = jnp.broadcast_to(ok[None], (G, W, ok.shape[1])).reshape(GW, -1)
-    s = jnp.where(ok, (q @ k.T) * scale, NEG_INF)
-    m_new = jnp.maximum(m_acc[...], jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_acc[...] - m_new)
-    l_acc[...] = l_acc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    o_acc[...] = o_acc[...] * corr + p @ v
-    m_acc[...] = m_new
+    ok = _cache_ok(kpos_ref, qpos_ref, lo_ref)     # (G*W, ps)
+    for h in range(hg):
+        q = q_ref[0, h].astype(jnp.float32)        # (G*W, hd)
+        k = _kv_head(ck_ref, h, sk_ref)
+        v = _kv_head(cv_ref, h, sv_ref)
+        s = jnp.where(ok, _scores(q, k) * scale, NEG_INF)
+        m_prev = m_acc[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_acc[h] = l_acc[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        o_acc[h] = o_acc[h] * corr + _pv(p, v)
+        m_acc[h] = m_new
 
     @pl.when(i == nblocks - 1)
     def _emit():
         # all-masked rows: clamp m like the oracle's m_safe so partials
         # compare exactly (l stays 0, so the merge ignores them anyway)
-        m_safe = jnp.maximum(m_acc[...], NEG_INF / 2)
-        o_ref[0, 0] = jnp.concatenate([o_acc[...], m_safe, l_acc[...]],
-                                      axis=-1)
+        for h in range(hg):
+            m_safe = jnp.maximum(m_acc[h], NEG_INF / 2)
+            o_ref[0, h] = jnp.concatenate([o_acc[h], m_safe, l_acc[h]],
+                                          axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_cache_attention(q, pool_k, pool_v, scale_k, scale_v, block_table,
-                          key_pos, q_pos, lo, *, interpret=True):
+                          key_pos, q_pos, lo, *, interpret):
     """Cache-only paged page walk (the dense half of the verify split when
     the W×W tree half runs as ``sparse_tree_attention_partial``).
 
     Same operands as ``paged_tree_attention`` minus the tree ones; the grid
-    is (B, Hkv, max_pages) — no trailing tree block.  Returns merge
+    is (B, Hkv // hg, max_pages) — no trailing tree block.  Returns merge
     partials ``(o (B, W, Hq, hd) f32 unnormalized, m (B, Hq, W),
     l (B, Hq, W))`` in the ``cm.merge_partials`` layout.
     """
@@ -297,32 +355,29 @@ def paged_cache_attention(q, pool_k, pool_v, scale_k, scale_v, block_table,
     P, ps, Hkv = pool_k.shape[0], pool_k.shape[1], pool_k.shape[2]
     maxp = block_table.shape[1]
     G = Hq // Hkv
+    hg = _head_group(Hkv)
+    nh = Hkv // hg
     tbl = jnp.where(block_table < 0, P - 1, block_table).astype(jnp.int32)
-    qg = q.reshape(B, W, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(
-        B, Hkv, G * W, hd)
+    kpos, qcol, locol = _row_operands(key_pos, q_pos, lo, G, ps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv, maxp),
+        grid=(B, nh, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, G * W, hd), lambda b, h, i, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
+            pl.BlockSpec((1, hg, G * W, hd), lambda b, h, i, t: (b, h, 0, 0)),
+            pl.BlockSpec((1, ps, hg, hd),
                          lambda b, h, i, t: (t[b, i], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
+            pl.BlockSpec((1, ps, hg, hd),
                          lambda b, h, i, t: (t[b, i], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, i, t: (t[b, i], h)),
-            pl.BlockSpec((1, 1), lambda b, h, i, t: (t[b, i], h)),
-            pl.BlockSpec((1, ps), lambda b, h, i, t: (b, i)),
-            pl.BlockSpec((1, W), lambda b, h, i, t: (b, 0)),
-            pl.BlockSpec((1, W), lambda b, h, i, t: (b, 0)),
+            pl.BlockSpec((1, hg, 1), lambda b, h, i, t: (t[b, i], h, 0)),
+            pl.BlockSpec((1, hg, 1), lambda b, h, i, t: (t[b, i], h, 0)),
+            pl.BlockSpec((1, 1, 1, ps), lambda b, h, i, t: (b, i, 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i, t: (b, 0, 0)),
+            pl.BlockSpec((1, G * W, 1), lambda b, h, i, t: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G * W, hd + 2),
+        out_specs=pl.BlockSpec((1, hg, G * W, hd + 2),
                                lambda b, h, i, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G * W, hd), jnp.float32),
-            pltpu.VMEM((G * W, 1), jnp.float32),
-            pltpu.VMEM((G * W, 1), jnp.float32),
-        ],
+        scratch_shapes=_scratch(hg, G * W, hd),
     )
 
     def kernel(tbl_ref, *refs):
@@ -333,8 +388,9 @@ def paged_cache_attention(q, pool_k, pool_v, scale_k, scale_v, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd + 2), jnp.float32),
         interpret=interpret,
-    )(tbl, qg, pool_k, pool_v, scale_k.astype(jnp.float32),
-      scale_v.astype(jnp.float32), key_pos, q_pos, lo)
+    )(tbl, _group_queries(q, Hkv), pool_k, pool_v,
+      scale_k.astype(jnp.float32)[..., None],
+      scale_v.astype(jnp.float32)[..., None], kpos, qcol, locol)
     pk = packed.reshape(B, Hkv, G, W, hd + 2)
     o = pk[..., :hd].transpose(0, 3, 1, 2, 4).reshape(B, W, Hq, hd)
     m = pk[..., hd].reshape(B, Hkv * G, W)

@@ -72,6 +72,7 @@ from repro.core import arca
 from repro.core.speculative import tree as T
 from repro.core.speculative.medusa import init_medusa
 from repro.data.pipeline import MarkovDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import get_model
 from repro.runtime.engine import BatchEngine, SpeculativeEngine
 from repro.runtime.faults import FaultPlan
@@ -218,26 +219,57 @@ def _replay(eng, args, data, cfg, adaptive=None):
     else:
         results, stats = serve_static(eng, reqs, batch=args.batch)
         label = args.sched
+    al = stats.get("acceptance_length")
     print(f"[serve] {label} x{args.requests} reqs "
           f"(poisson rate {args.rate}/s, B={args.batch}): "
           f"{stats['emitted_total']} tokens in {stats['makespan_s']:.2f}s "
           f"({stats['tok_s']:.1f} tok/s aggregate), "
-          f"latency mean {stats['latency_mean_s']:.2f}s "
+          + (f"acceptance length {al:.2f}, " if al is not None else "")
+          + f"latency mean {stats['latency_mean_s']:.2f}s "
           f"p50 {stats['latency_p50_s']:.2f}s "
           f"p95 {stats['latency_p95_s']:.2f}s, "
           f"queue wait mean {stats['queue_wait_mean_s']:.2f}s "
           f"p95 {stats['queue_wait_p95_s']:.2f}s")
+    drained = eng.sched_pool_conserved() and eng.sched_drained()
+    if stats["states"] != {"DONE": len(results)} or not drained:
+        raise SystemExit(f"[serve] REPLAY VIOLATION: states "
+                         f"{stats['states']}, pages drained: {drained}")
     return results, stats
 
 
-def main():
+def _measured_tree(cfg, accs, args, build):
+    """``--width 0`` on a TPU: time each candidate width's compiled step
+    on this chip (``arca.profile_engine``) and keep the measured argmax.
+    ``build(spec, max_len)`` makes the profiling engine, sized for the
+    deepest candidate."""
+    widths = (1, 2, 4, 8, 16)
+    specs = {w: T.candidate_spec(accs, w) for w in widths}
+    eng = build(specs[max(widths)], args.prompt_len + args.tokens + max(
+        s.max_depth for s in specs.values()))
+    time_fn = arca.profile_engine(eng, widths, accs=accs, batch=args.batch,
+                                  prompt_len=args.prompt_len)
+    strat = arca.best(arca.choose_strategy(cfg, accs, ctx=args.prompt_len,
+                                           time_fn=time_fn, widths=widths))
+    print(f"[serve] measured ARCA chose width={strat.width} "
+          f"(E[AL]={strat.acceptance:.2f}, "
+          f"step {strat.step_time * 1e3:.2f} ms)")
+    return strat.tree
+
+
+def main(argv=None):
+    """Parse ``argv`` (default: the command line) and serve.  Returns what
+    the run produced — ``(results, stats)`` for a replay, ``(tokens,
+    stats)`` for a fixed batch — and raises ``SystemExit`` when a gate
+    (replay states and page drain, HCMP parity, fault tolerance) fails."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b-smoke")
     ap.add_argument("--mode", default="ghidorah",
                     choices=["ghidorah", "sequential"])
     ap.add_argument("--width", type=int, default=0,
-                    help="verification width (0 = let ARCA choose "
-                         "analytically)")
+                    help="verification width (0 = let ARCA choose: by "
+                         "timing each width's compiled step on a TPU, by "
+                         "its analytic model elsewhere)")
     ap.add_argument("--spec-width", default=None,
                     help="verification width: an int (same as --width, "
                          "takes precedence) or 'auto' — MEASURED ARCA: the "
@@ -341,7 +373,7 @@ def main():
                          "or leaked page (the CI gate); auto = ARCA times "
                          "both partitions and picks the faster "
                          "(ghidorah only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     # ---- argument validation: fail fast with a clear error, never hang
     # or crash layers deeper --------------------------------------------
     if args.tokens < 1:
@@ -404,11 +436,14 @@ def main():
     if args.hcmp != "inline":
         # must run BEFORE the first jax computation: the second host
         # device can only be requested while the backend is uninitialized
-        from repro.core.hcmp.executors import ensure_host_devices
-        ndev = ensure_host_devices(2)
-        note = "" if ndev >= 2 else \
-            " (single device: overlap degrades to a serial schedule)"
-        print(f"[serve] hcmp {args.hcmp}: {ndev} host device(s){note}")
+        from repro.core.hcmp.executors import (ensure_host_devices,
+                                               executor_pair)
+        ensure_host_devices(2)
+        vdev, ddev = executor_pair()
+        note = "" if vdev != ddev else \
+            " (one device: overlap degrades to a serial schedule)"
+        print(f"[serve] hcmp {args.hcmp}: verify on {vdev}, draft on "
+              f"{ddev}{note}")
         # overlap-capable engine; "auto" measures and may switch back
         paged_kw["hcmp"] = "overlap"
 
@@ -430,12 +465,10 @@ def main():
                           **paged_kw)
         if args.arrivals != "none":
             if _fault_tolerant(args):
-                _replay_async(args, data, _once_then(
+                return _replay_async(args, data, _once_then(
                     eng, lambda: BatchEngine(model, params, max_len=max_len,
                                              chunk=args.chunk, **paged_kw)))
-            else:
-                _replay(eng, args, data, cfg)
-            return
+            return _replay(eng, args, data, cfg)
         t0 = time.perf_counter()
         out, stats = eng.generate(batch, args.tokens)
         dt = time.perf_counter() - t0
@@ -443,7 +476,7 @@ def main():
         print(f"[serve] sequential: {n_out} tokens "
               f"({args.batch} seq x chunk {args.chunk}) in {dt:.2f}s "
               f"({n_out / dt:.1f} tok/s)")
-        return
+        return out, stats
 
     heads = init_medusa(cfg, jax.random.PRNGKey(args.seed + 1))
     if args.heads_ckpt:
@@ -517,24 +550,29 @@ def main():
             return e
 
         if _fault_tolerant(args):
-            _replay_async(args, data, _once_then(eng, build_auto),
-                          adaptive=strategies)
-        else:
-            results, _ = _replay(eng, args, data, cfg, adaptive=strategies)
-            if args.hcmp == "overlap":
-                def build_inline():
-                    e = SpeculativeEngine(model, heads, params,
-                                          specs[max(widths)],
-                                          max_len=max_len, chunk=args.chunk,
-                                          **{**paged_kw, "hcmp": "inline"})
-                    e.set_strategy(start.tree)
-                    return e
-                _hcmp_gate(args, data, eng, results, build_inline,
-                           adaptive=strategies)
-        return
+            return _replay_async(args, data, _once_then(eng, build_auto),
+                                 adaptive=strategies)
+        results, stats = _replay(eng, args, data, cfg, adaptive=strategies)
+        if args.hcmp == "overlap":
+            def build_inline():
+                e = SpeculativeEngine(model, heads, params,
+                                      specs[max(widths)],
+                                      max_len=max_len, chunk=args.chunk,
+                                      **{**paged_kw, "hcmp": "inline"})
+                e.set_strategy(start.tree)
+                return e
+            _hcmp_gate(args, data, eng, results, build_inline,
+                       adaptive=strategies)
+        return results, stats
     if args.width:
         spec = T.build_tree(accs, args.width)
+    elif jax.default_backend() == "tpu":
+        spec = _measured_tree(cfg, accs, args, lambda sp, n: SpeculativeEngine(
+            model, heads, params, sp, max_len=n, chunk=args.chunk,
+            **paged_kw))
     else:
+        # the analytic Jetson model stands in only where no chip can be
+        # timed (the CPU benches that exercise it)
         strat = arca.best(arca.choose_strategy(cfg, accs, ctx=args.prompt_len))
         spec = strat.tree
         print(f"[serve] ARCA chose width={strat.width} "
@@ -577,20 +615,19 @@ def main():
             eng.set_tree_kernel(tk)
     if args.arrivals != "none":
         if _fault_tolerant(args):
-            _replay_async(args, data, _once_then(
+            return _replay_async(args, data, _once_then(
                 eng, lambda: SpeculativeEngine(model, heads, params, spec,
                                                max_len=max_len,
                                                chunk=args.chunk,
                                                **paged_kw)))
-        else:
-            results, _ = _replay(eng, args, data, cfg)
-            if args.hcmp == "overlap":
-                _hcmp_gate(args, data, eng, results,
-                           lambda: SpeculativeEngine(
-                               model, heads, params, spec, max_len=max_len,
-                               chunk=args.chunk,
-                               **{**paged_kw, "hcmp": "inline"}))
-        return
+        results, stats = _replay(eng, args, data, cfg)
+        if args.hcmp == "overlap":
+            _hcmp_gate(args, data, eng, results,
+                       lambda: SpeculativeEngine(
+                           model, heads, params, spec, max_len=max_len,
+                           chunk=args.chunk,
+                           **{**paged_kw, "hcmp": "inline"}))
+        return results, stats
     t0 = time.perf_counter()
     out, stats = eng.generate(batch, args.tokens)        # full batch: B >= 1
     dt = time.perf_counter() - t0
@@ -619,6 +656,7 @@ def main():
             raise SystemExit("[serve] HCMP OVERLAP VIOLATION: overlapped "
                              "draft/verify diverged from the inline "
                              "engine on the fixed batch")
+    return out, stats
 
 
 if __name__ == "__main__":

@@ -107,6 +107,15 @@ def _kv_dtype(kv_dtype):
     return jnp.dtype(kv_dtype)
 
 
+def _attn_backend(backend: Optional[str]) -> str:
+    """The attention backend an engine serves with: the caller's choice,
+    else the Pallas kernels on a TPU and the jnp reference elsewhere (the
+    CPU runs Pallas only in its interpreter, a test tool)."""
+    if backend is not None:
+        return backend
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
 def _eos_scalar(eos) -> jnp.ndarray:
     return jnp.asarray(_NO_EOS if eos is None else int(eos), jnp.int32)
 
@@ -511,6 +520,10 @@ class DecodeEngine(_PagedPoolMixin):
     reservation overshoot to the deepest candidate.  ``time_step`` measures
     one compiled step — ARCA's measured time source.
 
+    ``backend`` picks the attention path: ``"pallas"`` (the kernels in
+    ``kernels/``) or ``"ref"`` (the jnp reference); None serves Pallas on
+    a TPU and the reference elsewhere.
+
     ``kv_dtype`` picks the paged pool's storage dtype — ``"int8"``
     quantizes pages with per-page dequant scales (runtime/cache.py),
     shrinking bytes/token ~3.5x so the same pool bytes reserve more
@@ -521,7 +534,7 @@ class DecodeEngine(_PagedPoolMixin):
     measure both per shape."""
 
     def __init__(self, model, params, *, strategy: Optional[DecodeStrategy]
-                 = None, heads=None, max_len=512, window=0, backend="ref",
+                 = None, heads=None, max_len=512, window=0, backend=None,
                  chunk=8, paged=False, page_size=16, pool_pages=None,
                  hcmp="inline", kv_dtype=None, tree_kernel="dense"):
         if strategy is None:
@@ -571,7 +584,7 @@ class DecodeEngine(_PagedPoolMixin):
         self._registered: Dict[int, DecodeStrategy] = {}
         self._registered_depth = 0
         self.max_len, self.window = max_len, window
-        self.backend, self.chunk = backend, chunk
+        self.backend, self.chunk = _attn_backend(backend), chunk
         self._paged_init(paged=paged, page_size=page_size,
                          pool_pages=pool_pages)
         # every jit target below is a NAMED def (not a lambda): the
@@ -1041,7 +1054,7 @@ class BatchEngine(DecodeEngine):
     Output- and protocol-identical to the pre-unification BatchEngine."""
 
     def __init__(self, model, params, *, max_len=512, window=0,
-                 backend="ref", chunk=8, paged=False, page_size=16,
+                 backend=None, chunk=8, paged=False, page_size=16,
                  pool_pages=None, kv_dtype=None):
         super().__init__(model, params,
                          strategy=DecodeStrategy.sequential(),
@@ -1056,7 +1069,7 @@ class SpeculativeEngine(DecodeEngine):
     the pre-unification SpeculativeEngine."""
 
     def __init__(self, model, heads, params, tree_spec: TreeSpec, *,
-                 max_len=512, window=0, backend="ref", chunk=8, paged=False,
+                 max_len=512, window=0, backend=None, chunk=8, paged=False,
                  page_size=16, pool_pages=None, hcmp="inline",
                  kv_dtype=None, tree_kernel="dense"):
         super().__init__(model, params, heads=heads,

@@ -614,6 +614,8 @@ class ContinuousScheduler:
         self._t0 = time.perf_counter()
         self._boundary_i = 0
         self._n_chunks = 0
+        self._verify_steps = 0
+        self._accepted = 0
         self._max_resident = 0
         self._eos = None
         self._eos_val = int(_eos_scalar(None))
@@ -666,6 +668,8 @@ class ContinuousScheduler:
         self.events = []
         self._max_resident = 0
         self._n_chunks = 0
+        self._verify_steps = 0            # live row-steps, for acceptance
+        self._accepted = 0
         self._boundary_i = 0
         self._dirty = set()               # evicted rows not yet reset
         self._t0 = time.perf_counter()
@@ -891,6 +895,12 @@ class ContinuousScheduler:
             rem_np = self._rem_np = np.asarray(rem).copy()
             per_row = eng.sched_emitted(raw)
             self._n_chunks += 1
+            # raw[1] = (K, B) per-step accepted counts, already on the
+            # host after sched_emitted; masked/free rows are 0
+            # reprolint: disable=R3 (materialized by sched_emitted above)
+            n_acc = np.asarray(raw[1])
+            self._verify_steps += int(np.count_nonzero(n_acc))
+            self._accepted += int(n_acc.sum())
             for b in occupied:
                 if slots[b]["pending"] is None:
                     slots[b]["out"].extend(per_row[b])
@@ -988,6 +998,8 @@ class ContinuousScheduler:
                              key=lambda r: r.t_finish)
         stats = _aggregate(ordered, makespan)
         stats.update(admitted=len(ordered), chunks=self._n_chunks,
+                     acceptance_length=(self._accepted
+                                        / max(self._verify_steps, 1)),
                      max_resident=self._max_resident, batch=self.batch,
                      chunk=self.chunk, policy=self.policy.name,
                      age_limit=getattr(self.policy, "age_limit", 0),

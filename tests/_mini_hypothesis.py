@@ -1,8 +1,8 @@
-"""Deterministic fallback for the subset of `hypothesis` this suite uses.
+"""Deterministic stand-in for the subset of `hypothesis` this suite uses.
 
-The container may not ship hypothesis; property tests then fall back to this
-shim, which draws a fixed number of seeded pseudo-random examples per test
-(deterministic across runs) instead of erroring at collection.  API surface:
+The property tests import it directly, so every machine replays the same
+examples: it draws a fixed number of seeded pseudo-random examples per
+test (deterministic across runs).  API surface:
 ``given``, ``settings``, and ``strategies.{integers,floats,sampled_from,
 tuples}`` with ``.map``.  Shrinking/reporting are intentionally absent — on
 failure the raw example values appear in the assertion traceback.

@@ -3,7 +3,7 @@ import sys
 
 # allow running plain `pytest tests/` too
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# tests dir itself (for the _mini_hypothesis fallback import)
+# tests dir itself (for the _mini_hypothesis import)
 sys.path.insert(0, os.path.dirname(__file__))
 
 # smoke tests must see the single real CPU device (the 512-device flag is

@@ -1,10 +1,7 @@
 """Ring-buffer KV cache properties (hypothesis)."""
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # container may not ship hypothesis
-    from _mini_hypothesis import given, settings, strategies as st
+from _mini_hypothesis import given, settings, strategies as st
 
 from repro.runtime.cache import (batched_decode_mask, decode_mask, kv_write,
                                  prefill_mask)

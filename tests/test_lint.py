@@ -504,6 +504,20 @@ def test_r8_flags_unclamped_index_map(tmp_path):
                for f in found)
 
 
+def test_r8_flags_block_that_breaks_tpu_tiling(tmp_path):
+    """Fetch one kv head per block, as the kernels once did: the block's
+    second-minor dim (1) is neither the operand's Hkv nor a multiple of 8,
+    which Mosaic refuses and interpret mode never notices."""
+    src = _REAL_TREE.read_text()
+    assert "pl.BlockSpec((1, bs, hg, hd)," in src
+    found = _lint(tmp_path, {
+        "kernels/tree_attention.py":
+            src.replace("pl.BlockSpec((1, bs, hg, hd),",
+                        "pl.BlockSpec((1, bs, 1, hd),")}, rules=["R8"])
+    assert found and all(f.rule == "R8" for f in found)
+    assert any("tiling rule" in f.message for f in found)
+
+
 def test_r8_near_miss_real_kernel_verifies(tmp_path):
     # the committed kernel, verbatim: every index map proves in-bounds,
     # every out_spec tiles exactly once, for the whole config matrix

@@ -5,10 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # container may not ship hypothesis
-    from _mini_hypothesis import given, settings, strategies as st
+from _mini_hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.models import mamba2 as mb

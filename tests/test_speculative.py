@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # container may not ship hypothesis
-    from _mini_hypothesis import given, settings, strategies as st
+from _mini_hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.core.speculative import tree as T

@@ -1,10 +1,7 @@
 """Verification-tree properties (hypothesis) — paper §III-C1 machinery."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                    # container may not ship hypothesis
-    from _mini_hypothesis import given, settings, strategies as st
+from _mini_hypothesis import given, settings, strategies as st
 
 from repro.core.speculative import tree as T
 
