@@ -1,0 +1,7 @@
+"""Median over requests of (last-token stamp - first-token stamp) /
+(tokens - 1): the single-stream decoding speed (host clock)."""
+from bench import latency
+
+
+def read(run):
+    return latency.tpot_ms(run.requests, run.window_s, 50)
