@@ -87,39 +87,50 @@ def spec_step(model, params, heads, tree, state: SpecState, *, backend="ref",
     keeps decoding (runtime/scheduler.py evicts them at the chunk boundary).
     """
     cfg = model.cfg
-    cands, _ = draft_candidates(cfg, heads, state.hidden, cfg.medusa_top_k)
-    tree_tokens = expand_tree_tokens(tree, state.cur_token, cands)
-    logits, extras = model.verify(params, state.cache, tree_tokens, tree,
-                                  backend=backend, tree_kernel=tree_kernel)
-    acc = accept_walk(tree, tree_tokens, logits)
+    # named scopes group the step's device ops by phase in a profile
+    with jax.named_scope("draft"):
+        cands, _ = draft_candidates(cfg, heads, state.hidden,
+                                    cfg.medusa_top_k)
+        tree_tokens = expand_tree_tokens(tree, state.cur_token, cands)
+    with jax.named_scope("verify"):
+        logits, extras = model.verify(params, state.cache, tree_tokens,
+                                      tree, backend=backend,
+                                      tree_kernel=tree_kernel)
+        acc = accept_walk(tree, tree_tokens, logits)
 
-    # batched commit: per-sequence accepted chain / length / path
-    n_accept = acc["n_accept"]
-    if active is not None:
-        n_accept = jnp.where(active, n_accept, 0)
-    path_idx = tree.node_path[acc["last_node"]]              # (B,)
-    cache = model.commit(state.cache, extras, tree, acc["chain"],
-                         n_accept, path_idx)
+    with jax.named_scope("commit"):
+        # batched commit: per-sequence accepted chain / length / path
+        n_accept = acc["n_accept"]
+        if active is not None:
+            n_accept = jnp.where(active, n_accept, 0)
+        path_idx = tree.node_path[acc["last_node"]]          # (B,)
+        cache = model.commit(state.cache, extras, tree, acc["chain"],
+                             n_accept, path_idx)
 
-    hidden = extras["hidden"]                       # (B, W, d)
-    new_hidden = jnp.take_along_axis(
-        hidden, acc["last_node"][:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    cur_token = acc["bonus"]
-    if active is not None:
-        cur_token = jnp.where(active, cur_token, state.cur_token)
-        new_hidden = jnp.where(active[:, None], new_hidden, state.hidden)
-    new_state = SpecState(cache=cache, cur_token=cur_token,
-                          hidden=new_hidden)
+        hidden = extras["hidden"]                   # (B, W, d)
+        new_hidden = jnp.take_along_axis(
+            hidden, acc["last_node"][:, None, None].astype(jnp.int32),
+            axis=1)[:, 0]
+        cur_token = acc["bonus"]
+        if active is not None:
+            cur_token = jnp.where(active, cur_token, state.cur_token)
+            new_hidden = jnp.where(active[:, None], new_hidden,
+                                   state.hidden)
+        new_state = SpecState(cache=cache, cur_token=cur_token,
+                              hidden=new_hidden)
 
-    # emitted tokens: accepted children (chain[1:n]) then the bonus token.
-    # position j < n-1 emits tree_tokens[chain[j+1]]; position n-1 emits bonus.
-    idx = jnp.arange(tree.max_depth)[None, :]
-    chain_tokens = jnp.take_along_axis(tree_tokens, acc["chain"], axis=1)
-    child_shift = jnp.concatenate(
-        [chain_tokens[:, 1:], chain_tokens[:, -1:]], axis=1)
-    emitted = jnp.where(idx < (acc["n_accept"] - 1)[:, None], child_shift, 0)
-    emitted = jnp.where(idx == (acc["n_accept"] - 1)[:, None],
-                        acc["bonus"][:, None], emitted)
+        # emitted tokens: accepted children (chain[1:n]) then the bonus
+        # token.  position j < n-1 emits tree_tokens[chain[j+1]]; position
+        # n-1 emits bonus.
+        idx = jnp.arange(tree.max_depth)[None, :]
+        chain_tokens = jnp.take_along_axis(tree_tokens, acc["chain"],
+                                           axis=1)
+        child_shift = jnp.concatenate(
+            [chain_tokens[:, 1:], chain_tokens[:, -1:]], axis=1)
+        emitted = jnp.where(idx < (acc["n_accept"] - 1)[:, None],
+                            child_shift, 0)
+        emitted = jnp.where(idx == (acc["n_accept"] - 1)[:, None],
+                            acc["bonus"][:, None], emitted)
     return new_state, emitted, n_accept
 
 

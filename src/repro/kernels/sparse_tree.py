@@ -80,6 +80,7 @@ def sparse_tree_attention_partial(q, k_new, v_new, tree_mask, *, interpret):
                                lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd + 2), jnp.float32),
         interpret=interpret,
+        name="sparse_tree_attention_partial",
     )(_group_queries(q, Hkv), k_new, v_new, _group_mask(tree_mask, G))
     pk = packed.reshape(B, Hkv, G, W, hd + 2)
     o = pk[..., :hd].transpose(0, 3, 1, 2, 4).reshape(B, W, Hq, hd)
@@ -108,5 +109,6 @@ def sparse_tree_attention(q, k_new, v_new, tree_mask, *, interpret):
         out_specs=pl.BlockSpec((1, hg, G * W, hd), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         interpret=interpret,
+        name="sparse_tree_attention",
     )(_group_queries(q, Hkv), k_new, v_new, _group_mask(tree_mask, G))
     return _ungroup(out, W)

@@ -212,6 +212,7 @@ def tree_attention(q, ck, cv, k_new, v_new, key_pos, q_pos, lo, tree_mask,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         scratch_shapes=_scratch(hg, G * W, hd),
         interpret=interpret,
+        name="tree_attention",
     )(_group_queries(q, Hkv), ck, cv, k_new, v_new, kpos, qcol, locol,
       _group_mask(tree_mask, G))
     return _ungroup(out, W)
@@ -290,6 +291,7 @@ def paged_tree_attention(q, pool_k, pool_v, scale_k, scale_v, k_new, v_new,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         interpret=interpret,
+        name="paged_tree_attention",
     )(tbl, _group_queries(q, Hkv), pool_k, pool_v,
       scale_k.astype(jnp.float32)[..., None],
       scale_v.astype(jnp.float32)[..., None], k_new, v_new, kpos, qcol,
@@ -388,6 +390,7 @@ def paged_cache_attention(q, pool_k, pool_v, scale_k, scale_v, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd + 2), jnp.float32),
         interpret=interpret,
+        name="paged_cache_attention",
     )(tbl, _group_queries(q, Hkv), pool_k, pool_v,
       scale_k.astype(jnp.float32)[..., None],
       scale_v.astype(jnp.float32)[..., None], kpos, qcol, locol)

@@ -335,6 +335,7 @@ class SchedulableEngine(Protocol):
 
     # ---- the chunk step --------------------------------------------------
     def sched_step(self, state, done, rem, K, eos_val): ...
+    def sched_fetch(self, raw): ...
     def sched_emitted(self, raw): ...
 
     # ---- optional extensions (probed with getattr/hasattr) ---------------
@@ -1030,9 +1031,15 @@ class DecodeEngine(_PagedPoolMixin):
         return state, done, rem, (toks, ns)
 
     @staticmethod
+    def sched_fetch(raw):
+        """The chunk's ``(toks, ns)`` copied to the host, after the
+        boundary's ``done``/``rem`` sync has waited out the chunk."""
+        return jax.device_get(raw)
+
+    @staticmethod
     def sched_emitted(raw):
-        # the scheduler's ONE budgeted sync per boundary: materialize the
-        # chunk's token block exactly once
+        # per row, the tokens of the chunk's token block; a block still on
+        # the device is materialized here exactly once
         # reprolint: disable=R3 (intended boundary sync)
         toks, ns = (np.asarray(x) for x in raw)
         K, B = ns.shape

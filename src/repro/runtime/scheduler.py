@@ -176,6 +176,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.runtime.engine import _eos_scalar, _pow2_chunk
+from repro.runtime.telemetry import Telemetry
 
 # ---- request lifecycle states --------------------------------------------
 QUEUED = "QUEUED"            # submitted, waiting for a slot
@@ -251,7 +252,6 @@ def _aggregate(results: Sequence[RequestResult], makespan: float) -> dict:
         "latency_p95_s": pct(lats, 95),
         "latency_max_s": float(lats.max()) if lats.size else 0.0,
         "queue_wait_mean_s": float(waits.mean()) if waits.size else 0.0,
-        "queue_wait_p50_s": pct(waits, 50),
         "queue_wait_p95_s": pct(waits, 95),
     }
 
@@ -538,12 +538,12 @@ class ContinuousScheduler:
 
     Works with any engine implementing the slot protocol
     (``sched_prefill`` / ``sched_blank`` / ``sched_insert`` /
-    ``sched_reset`` / ``sched_step`` / ``sched_emitted`` plus the paged
-    reservation hooks ``sched_can_admit`` / ``sched_release`` /
-    ``sched_abort`` / ``sched_footprint`` and, for ``prefill_chunk``, the
-    piecewise admission hook ``sched_extend`` gated by
-    ``sched_chunked_ok`` — the unified ``DecodeEngine`` implements all of
-    it once; ``BatchEngine`` / ``SpeculativeEngine`` are its aliases).
+    ``sched_reset`` / ``sched_step`` / ``sched_fetch`` / ``sched_emitted``
+    plus the paged reservation hooks ``sched_can_admit`` /
+    ``sched_release`` / ``sched_abort`` / ``sched_footprint`` and, for
+    ``prefill_chunk``, the piecewise admission hook ``sched_extend`` gated
+    by ``sched_chunked_ok`` — the unified ``DecodeEngine`` implements all
+    of it once; ``BatchEngine`` / ``SpeculativeEngine`` are its aliases).
 
     ``policy`` picks which queued request a freed row takes (``"fifo"`` /
     ``"sjf"`` / ``"lpt"`` or an ``AdmissionPolicy``); ``age_limit=N``
@@ -599,6 +599,8 @@ class ContinuousScheduler:
             self._strategy_table = engine.register_strategies(
                 {w: s.tree for w, s in self.adaptive.strategies.items()})
         self.faults = faults
+        # host spans and counters of boundary(), per stream (start())
+        self.telemetry = Telemetry()
         # introspection for tests / debugging, populated by serve()
         self.last_state = None
         self.events: List[tuple] = []
@@ -672,6 +674,7 @@ class ContinuousScheduler:
         self._accepted = 0
         self._boundary_i = 0
         self._dirty = set()               # evicted rows not yet reset
+        self.telemetry.reset()
         self._t0 = time.perf_counter()
 
     def submit(self, request: Request) -> None:
@@ -717,7 +720,9 @@ class ContinuousScheduler:
         kept = s["out"][:req.n_tokens]
         tail = kept[s["flushed"]:]
         if tail:
-            emitted[req.req_id] = [int(t) for t in tail]
+            # an unflushed first token is still an unsynced device scalar
+            with self.telemetry.span("sched.wait", req=req.req_id):
+                emitted[req.req_id] = [int(t) for t in tail]
         finished.append(self._finalize(req, kept, s["t"], state))
         eng = self.engine
         getattr(eng, "sched_abort", eng.sched_release)(b)
@@ -728,10 +733,11 @@ class ContinuousScheduler:
         self.events.append(("abort", req.req_id, b))
 
     def _apply_aborts(self, t_now: float, emitted: dict,
-                      finished: list) -> None:
+                      finished: list) -> bool:
         """Boundary-start lifecycle sweep: expired deadlines join the
         pending cancellations, then every abort lands — queued requests
-        finalize with zero tokens, resident rows release mid-flight."""
+        finalize with zero tokens, resident rows release mid-flight.
+        True when there was an abort to apply (``sched.aborts``)."""
         for s in self._slots:
             if s is not None and s["req"].deadline is not None \
                     and t_now > s["req"].deadline:
@@ -740,213 +746,250 @@ class ContinuousScheduler:
             if r.deadline is not None and t_now > r.deadline:
                 self._aborts.setdefault(r.req_id, TIMED_OUT)
         if not self._aborts:
-            return
+            return False
         aborts, self._aborts = self._aborts, {}
-        rows = {s["req"].req_id: b for b, s in enumerate(self._slots)
-                if s is not None}
-        for req_id, state in aborts.items():
-            if req_id in self._results:
-                continue                  # already terminal: no-op
-            if req_id in rows:
-                self._abort_row(rows[req_id], state, emitted, finished)
-                continue
-            i = next((j for j, r in enumerate(self._pending)
-                      if r.req_id == req_id), None)
-            if i is None:
-                continue                  # unknown id: no-op
-            req = self._pending.pop(i)
-            finished.append(self._finalize(req, [], self.now(), state))
-            self.events.append(("abort", req_id, -1))
+        with self.telemetry.span("sched.aborts"):
+            rows = {s["req"].req_id: b for b, s in enumerate(self._slots)
+                    if s is not None}
+            for req_id, state in aborts.items():
+                if req_id in self._results:
+                    continue              # already terminal: no-op
+                if req_id in rows:
+                    self._abort_row(rows[req_id], state, emitted, finished)
+                    continue
+                i = next((j for j, r in enumerate(self._pending)
+                          if r.req_id == req_id), None)
+                if i is None:
+                    continue              # unknown id: no-op
+                req = self._pending.pop(i)
+                finished.append(self._finalize(req, [], self.now(), state))
+                self.events.append(("abort", req_id, -1))
+        return True
 
     def boundary(self) -> BoundaryReport:
         """Run ONE admit/chunk/evict iteration and report what it emitted.
         Never sleeps: an idle report carries the earliest queued arrival
         so the caller decides whether to wait (``serve()``) or keep the
-        event loop spinning (the async server)."""
+        event loop spinning (the async server).  Every phase runs in a
+        ``sched.*`` span of ``self.telemetry`` (``runtime/telemetry.py``)."""
         eng, B, C = self.engine, self.batch, self.prefill_chunk
         eos, eos_val = self._eos, self._eos_val
         slots, done_np, rem_np = self._slots, self._done_np, self._rem_np
+        tel, span = self.telemetry, self.telemetry.span
         emitted: Dict[int, list] = {}
         finished: List[RequestResult] = []
         self._boundary_i += 1
-        if self.faults is not None:
-            # stalls sleep here; an injected crash raises out of boundary()
-            self.faults.on_boundary(self._boundary_i)
-        # ---- cancels / expired deadlines take effect at the boundary ----
-        self._apply_aborts(self.now(), emitted, finished)
+        with tel.boundary() as bd:
+            if self.faults is not None:
+                # stalls sleep here; an injected crash raises out of
+                # boundary()
+                self.faults.on_boundary(self._boundary_i)
+            # ---- cancels / expired deadlines take effect at the boundary -
+            if self._apply_aborts(self.now(), emitted, finished):
+                bd.turnover = True
 
-        def can_admit(r):
-            return eng.sched_can_admit(len(r.tokens), r.n_tokens)
+            def can_admit(r):
+                return eng.sched_can_admit(len(r.tokens), r.n_tokens)
 
-        def footprint(r):
-            return eng.sched_footprint(len(r.tokens), r.n_tokens)
+            def footprint(r):
+                return eng.sched_footprint(len(r.tokens), r.n_tokens)
 
-        # ---- advance chunked prefills: one piece per row/boundary ----
-        for b in range(B):
-            s = slots[b]
-            if s is None or s.get("pending") is None:
-                continue
-            rest = s["pending"]
-            piece = rest[:C]
-            padded = np.zeros((1, C), np.int32)
-            padded[0, :len(piece)] = piece
-            self._dev, last = eng.sched_extend(self._dev, b, padded,
-                                               len(piece))
-            self.events.append(("extend", s["req"].req_id, b))
-            if len(rest) > C:
-                s["pending"] = rest[C:]
-            else:                     # last piece: the row goes LIVE
-                s["pending"] = None
-                s["out"] = [last]     # unsynced device scalar, like
-                done_np[b] = (eos is not None  # an admission's `first`
-                              and int(last) == eos_val)
-                rem_np[b] = max(s["req"].n_tokens - 1, 0)
-                self._state_of[s["req"].req_id] = DECODING
-                self.events.append(("prefill_done", s["req"].req_id, b))
+            # ---- advance chunked prefills: one piece per row/boundary ----
+            for b in range(B):
+                s = slots[b]
+                if s is None or s.get("pending") is None:
+                    continue
+                bd.turnover = True
+                rid = s["req"].req_id
+                with span("sched.extend", req=rid):
+                    rest = s["pending"]
+                    piece = rest[:C]
+                    padded = np.zeros((1, C), np.int32)
+                    padded[0, :len(piece)] = piece
+                    self._dev, last = eng.sched_extend(self._dev, b, padded,
+                                                       len(piece))
+                    self.events.append(("extend", rid, b))
+                    if len(rest) > C:
+                        s["pending"] = rest[C:]
+                    else:                 # last piece: the row goes LIVE
+                        s["pending"] = None
+                        s["out"] = [last]  # unsynced device scalar, like
+                        if eos is not None:  # an admission's `first`
+                            with span("sched.wait", req=rid):
+                                done_np[b] = int(last) == eos_val
+                        else:
+                            done_np[b] = False
+                        rem_np[b] = max(s["req"].n_tokens - 1, 0)
+                        self._state_of[rid] = DECODING
+                        self.events.append(("prefill_done", rid, b))
 
-        # ---- admit arrived requests into free rows (policy order) ----
-        # ONE arrival cutoff for the whole boundary: pick and the
-        # aging filter below must agree on who was visible, or a
-        # request arriving mid-dispatch would be aged (and promoted)
-        # without ever having been passed over
-        t_bound = self.now()
-        admitted_n, free_rows = 0, False
-        # injected admission-time pool exhaustion: defer everything this
-        # boundary, exactly like a real exhausted pool would
-        blocked = (self.faults is not None and bool(self._pending)
-                   and self.faults.block_admission())
-        if blocked:
-            free_rows = any(s is None for s in slots)
-        for b in range(B):
-            if blocked or slots[b] is not None or not self._pending:
-                continue
-            idx = self.policy.pick(self._pending, t_bound, can_admit,
-                                   footprint, self._dev is None)
-            if idx is None:           # nothing arrived / nothing the
-                free_rows = True      # pool can fund: leave rows empty
-                break
-            req = self._pending.pop(idx)
-            # reprolint: disable=R3 (req.tokens is a host list, no sync)
-            prompt_np = np.asarray(req.tokens, np.int32)
-            S = len(prompt_np)
-            chunked = bool(C) and S > C
-            prompt = (prompt_np[:C] if chunked else prompt_np)[None]
-            if self._dev is None:     # bootstrap the bank once
-                row = eng.sched_prefill({"tokens": prompt})
-                self._dev = eng.sched_blank(row, B)
-                self._dev = eng.sched_insert(self._dev, b, row,
-                                             prompt_len=S,
-                                             n_tokens=req.n_tokens)
-                first = eng.sched_first(row)
-            else:                     # ONE fused prefill+insert dispatch
-                self._dev, first = eng.sched_admit(self._dev, b,
-                                                   {"tokens": prompt},
-                                                   n_tokens=req.n_tokens,
-                                                   reserve_len=S)
-            self._dirty.discard(b)    # insert overwrote the whole row
-            if chunked:               # rest of the prompt lands piece-
-                slots[b] = {"req": req, "out": [], "t": self.now(),
-                            "pending": prompt_np[C:], "flushed": 0}
-                done_np[b] = True     # masked until the last piece
-                rem_np[b] = 0
-                self._state_of[req.req_id] = PREFILLING
-            else:
-                # `first` may be an unsynced device scalar — only force
-                # it when EOS filtering needs the value now
-                slots[b] = {"req": req, "out": [first], "t": self.now(),
-                            "pending": None, "flushed": 0}
-                done_np[b] = eos is not None and int(first) == eos_val
-                rem_np[b] = max(req.n_tokens - 1, 0)
-                self._state_of[req.req_id] = DECODING
-            admitted_n += 1
-            self.events.append(("admit", req.req_id, b))
-        # aging counts boundaries a request was PASSED OVER: another
-        # request was admitted past it, or a free row stayed empty
-        # because its own reservation could not be funded.  Waiting
-        # behind a FULL bank ages nobody — otherwise ordinary
-        # saturation would push every request past age_limit and
-        # permanently degrade SJF/LPT to FIFO.
-        if admitted_n or free_rows:
-            for r in self._pending:
-                if r.arrival <= t_bound:
-                    r.age += 1
-        if self._dirty and self._dev is not None:
-            # rows left empty: one batched reset (clears aborted rows'
-            # block tables BEFORE the next chunk can touch freed pages)
-            self._dev = eng.sched_reset(self._dev, sorted(self._dirty))
-            self._dirty.clear()
-        occupied = [b for b in range(B) if slots[b] is not None]
-        self._max_resident = max(self._max_resident, len(occupied))
-        if not occupied:
-            nxt = self._pending[0].arrival if self._pending else None
-            return BoundaryReport(emitted, finished, True, nxt,
-                                  self._boundary_i)
+            # ---- admit arrived requests into free rows (policy order) ----
+            # ONE arrival cutoff for the whole boundary: pick and the
+            # aging filter below must agree on who was visible, or a
+            # request arriving mid-dispatch would be aged (and promoted)
+            # without ever having been passed over
+            t_bound = self.now()
+            admitted_n, free_rows = 0, False
+            # injected admission-time pool exhaustion: defer everything
+            # this boundary, exactly like a real exhausted pool would
+            blocked = (self.faults is not None and bool(self._pending)
+                       and self.faults.block_admission())
+            if blocked:
+                free_rows = any(s is None for s in slots)
+            for b in range(B):
+                if blocked or slots[b] is not None or not self._pending:
+                    continue
+                idx = self.policy.pick(self._pending, t_bound, can_admit,
+                                       footprint, self._dev is None)
+                if idx is None:           # nothing arrived / nothing the
+                    free_rows = True      # pool can fund: leave rows empty
+                    break
+                req = self._pending.pop(idx)
+                bd.turnover = True
+                with span("sched.admit", req=req.req_id):
+                    # reprolint: disable=R3 (req.tokens: host list, no sync)
+                    prompt_np = np.asarray(req.tokens, np.int32)
+                    S = len(prompt_np)
+                    chunked = bool(C) and S > C
+                    prompt = (prompt_np[:C] if chunked else prompt_np)[None]
+                    if self._dev is None:     # bootstrap the bank once
+                        row = eng.sched_prefill({"tokens": prompt})
+                        self._dev = eng.sched_blank(row, B)
+                        self._dev = eng.sched_insert(self._dev, b, row,
+                                                     prompt_len=S,
+                                                     n_tokens=req.n_tokens)
+                        with span("sched.wait", req=req.req_id):
+                            first = eng.sched_first(row)
+                    else:                 # ONE fused prefill+insert dispatch
+                        self._dev, first = eng.sched_admit(
+                            self._dev, b, {"tokens": prompt},
+                            n_tokens=req.n_tokens, reserve_len=S)
+                    self._dirty.discard(b)  # insert overwrote the whole row
+                    if chunked:           # rest of the prompt lands piece-
+                        slots[b] = {"req": req, "out": [], "t": self.now(),
+                                    "pending": prompt_np[C:], "flushed": 0}
+                        done_np[b] = True  # masked until the last piece
+                        rem_np[b] = 0
+                        self._state_of[req.req_id] = PREFILLING
+                    else:
+                        # `first` may be an unsynced device scalar — only
+                        # force it when EOS filtering needs the value now
+                        slots[b] = {"req": req, "out": [first],
+                                    "t": self.now(), "pending": None,
+                                    "flushed": 0}
+                        if eos is not None:
+                            with span("sched.wait", req=req.req_id):
+                                done_np[b] = int(first) == eos_val
+                        else:
+                            done_np[b] = False
+                        rem_np[b] = max(req.n_tokens - 1, 0)
+                        self._state_of[req.req_id] = DECODING
+                    admitted_n += 1
+                    tel.admitted += 1
+                    self.events.append(("admit", req.req_id, b))
+            # aging counts boundaries a request was PASSED OVER: another
+            # request was admitted past it, or a free row stayed empty
+            # because its own reservation could not be funded.  Waiting
+            # behind a FULL bank ages nobody — otherwise ordinary
+            # saturation would push every request past age_limit and
+            # permanently degrade SJF/LPT to FIFO.
+            if admitted_n or free_rows:
+                for r in self._pending:
+                    if r.arrival <= t_bound:
+                        r.age += 1
+            if self._dirty and self._dev is not None:
+                # rows left empty: one batched reset (clears aborted rows'
+                # block tables BEFORE the next chunk can touch freed pages)
+                bd.turnover = True
+                with span("sched.reset"):
+                    self._dev = eng.sched_reset(self._dev,
+                                                sorted(self._dirty))
+                    self._dirty.clear()
+            occupied = [b for b in range(B) if slots[b] is not None]
+            self._max_resident = max(self._max_resident, len(occupied))
+            if not occupied:
+                nxt = self._pending[0].arrival if self._pending else None
+                return BoundaryReport(emitted, finished, True, nxt,
+                                      self._boundary_i)
 
-        # ---- run one chunk over the whole bank -----------------------
-        live = [b for b in occupied if not done_np[b] and rem_np[b] > 0]
-        if live:
-            K = _pow2_chunk(self.chunk, int(rem_np[live].max()))
-            self._dev, done, rem, raw = eng.sched_step(
-                self._dev, done_np, rem_np, K, eos_val)
-            # the boundary's budgeted sync: done/rem cross with the chunk
-            # reprolint: disable=R3 (intended boundary sync)
-            done_np = self._done_np = np.asarray(done).copy()
-            # reprolint: disable=R3 (intended boundary sync)
-            rem_np = self._rem_np = np.asarray(rem).copy()
-            per_row = eng.sched_emitted(raw)
-            self._n_chunks += 1
-            # raw[1] = (K, B) per-step accepted counts, already on the
-            # host after sched_emitted; masked/free rows are 0
-            # reprolint: disable=R3 (materialized by sched_emitted above)
-            n_acc = np.asarray(raw[1])
-            self._verify_steps += int(np.count_nonzero(n_acc))
-            self._accepted += int(n_acc.sum())
+            # ---- run one chunk over the whole bank -----------------------
+            live = [b for b in occupied if not done_np[b] and rem_np[b] > 0]
+            if live:
+                K = _pow2_chunk(self.chunk, int(rem_np[live].max()))
+                with span("sched.dispatch") as d:
+                    self._dev, done, rem, raw = eng.sched_step(
+                        self._dev, done_np, rem_np, K, eos_val)
+                bd.dispatched = d.end
+                # the boundary's budgeted sync: done/rem cross with the
+                # chunk
+                with span("sched.wait") as w:
+                    # reprolint: disable=R3 (intended boundary sync)
+                    done_np = self._done_np = np.asarray(done).copy()
+                    # reprolint: disable=R3 (intended boundary sync)
+                    rem_np = self._rem_np = np.asarray(rem).copy()
+                bd.returned = w.end
+                with span("sched.fetch"):
+                    raw = eng.sched_fetch(raw)
+                with span("sched.unpack"):
+                    per_row = eng.sched_emitted(raw)
+                    self._n_chunks += 1
+                    # raw[1] = (K, B) per-step accepted counts, on the
+                    # host since sched_fetch; masked/free rows are 0
+                    n_acc = raw[1]
+                    self._verify_steps += int(np.count_nonzero(n_acc))
+                    self._accepted += int(n_acc.sum())
+                    for b in occupied:
+                        if slots[b]["pending"] is None:
+                            slots[b]["out"].extend(per_row[b])
+                    if self.adaptive is not None:
+                        self.adaptive.observe(n_acc, eng.strategy.width)
+
+            # ---- flush newly available tokens (the streaming boundary) ---
             for b in occupied:
-                if slots[b]["pending"] is None:
-                    slots[b]["out"].extend(per_row[b])
-            if self.adaptive is not None:
-                # raw[1] = (K, B) per-step accepted counts; masked/free
-                # rows are 0 and dropped by the EMA
-                self.adaptive.observe(raw[1], eng.strategy.width)
+                s = slots[b]
+                if s is None or s["pending"] is not None:
+                    continue
+                if not s["flushed"] and s["out"]:
+                    # the admission's (or last piece's) first token is an
+                    # unsynced device scalar until now
+                    with span("sched.wait", req=s["req"].req_id):
+                        s["out"][0] = int(s["out"][0])
+                with span("sched.flush"):
+                    avail = min(len(s["out"]), s["req"].n_tokens)
+                    if avail > s["flushed"]:
+                        emitted[s["req"].req_id] = s["out"][s["flushed"]:avail]
+                        s["flushed"] = avail
 
-        # ---- flush newly available tokens (the streaming boundary) ---
-        for b in occupied:
-            s = slots[b]
-            if s is None or s["pending"] is not None:
-                continue
-            avail = min(len(s["out"]), s["req"].n_tokens)
-            if avail > s["flushed"]:
-                emitted[s["req"].req_id] = [
-                    int(t) for t in s["out"][s["flushed"]:avail]]
-                s["flushed"] = avail
+            # ---- evict finished rows (EOS / budget / capacity freeze) ----
+            for b in occupied:
+                s = slots[b]
+                if s is None or s["pending"] is not None:
+                    continue              # aborted / still prefilling
+                budget = s["req"].n_tokens
+                if not (done_np[b] or rem_np[b] <= 0
+                        or len(s["out"]) >= budget):
+                    continue
+                bd.turnover = True
+                with span("sched.evict", req=s["req"].req_id):
+                    kept = s["out"][:budget]
+                    finished.append(self._finalize(s["req"], kept, s["t"],
+                                                   DONE))
+                    eng.sched_release(b)  # paged: pages back to the pool NOW
+                    self._dirty.add(b)    # reset lazily unless re-admitted
+                    slots[b] = None
+                    done_np[b] = True
+                    rem_np[b] = 0
+                    self.events.append(("evict", s["req"].req_id, b))
 
-        # ---- evict finished rows (EOS / budget / capacity freeze) ----
-        for b in occupied:
-            s = slots[b]
-            if s is None or s["pending"] is not None:
-                continue              # aborted / still prefilling
-            budget = s["req"].n_tokens
-            if not (done_np[b] or rem_np[b] <= 0
-                    or len(s["out"]) >= budget):
-                continue
-            kept = s["out"][:budget]
-            finished.append(self._finalize(s["req"], kept, s["t"], DONE))
-            eng.sched_release(b)      # paged: pages back to the pool NOW
-            self._dirty.add(b)        # reset lazily unless re-admitted
-            slots[b] = None
-            done_np[b] = True
-            rem_np[b] = 0
-            self.events.append(("evict", s["req"].req_id, b))
-
-        # ---- adaptive: re-decide the decode strategy at the boundary -
-        if self.adaptive is not None and live:
-            new_w = self.adaptive.pick(eng.strategy.width)
-            if new_w is not None:
-                old_w = eng.strategy.width
-                eng.set_strategy(self._strategy_table[new_w])
-                self.events.append(("switch", old_w, new_w))
-        return BoundaryReport(emitted, finished, False, None,
-                              self._boundary_i)
+            # ---- adaptive: re-decide the decode strategy at the boundary -
+            if self.adaptive is not None and live:
+                new_w = self.adaptive.pick(eng.strategy.width)
+                if new_w is not None:
+                    old_w = eng.strategy.width
+                    eng.set_strategy(self._strategy_table[new_w])
+                    self.events.append(("switch", old_w, new_w))
+            return BoundaryReport(emitted, finished, False, None,
+                                  self._boundary_i)
 
     def fail_all(self, error=None) -> List[RequestResult]:
         """Replica-crash cleanup: finalize EVERY in-flight and queued
@@ -1003,7 +1046,8 @@ class ContinuousScheduler:
                      max_resident=self._max_resident, batch=self.batch,
                      chunk=self.chunk, policy=self.policy.name,
                      age_limit=getattr(self.policy, "age_limit", 0),
-                     prefill_chunk=self.prefill_chunk)
+                     prefill_chunk=self.prefill_chunk,
+                     host=self.telemetry.snapshot())
         if self.adaptive is not None:
             stats.update(
                 strategy_switches=[
