@@ -38,11 +38,42 @@ def medusa_logits(cfg, heads, hidden):
     return jnp.moveaxis(out, 0, -2)[..., :cfg.vocab_size]
 
 
+LANES = 128        # TPU vector lane width: the block of the two-stage top-k
+
+
+def blocked_top_k(x, k):
+    """``jax.lax.top_k(x, k)`` over the last axis, bit for bit, without
+    sorting the whole row (on a TPU a wide top-k lowers to a full sort).
+
+    The k largest values lie in the k blocks with the largest maxima, with
+    ties to the lower index as ``lax.top_k`` breaks them: rank the maxima
+    of the 128-lane blocks, gather the k best (every block, where there are
+    no more) in vocabulary order, and take the top k of those candidates.
+    Exact for inputs without -0.0 (``max`` does not tell it from 0.0),
+    such as probabilities."""
+    n = x.shape[-1]
+    nb = -(-n // LANES)
+    kb = min(k, nb)
+    pad = nb * LANES - n
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                    constant_values=-jnp.inf)
+    xb = x.reshape(x.shape[:-1] + (nb, LANES))
+    _, blk = jax.lax.top_k(xb.max(axis=-1), kb)
+    blk = jnp.sort(blk, axis=-1)                      # vocabulary order
+    cand = jnp.take_along_axis(xb, blk[..., None], axis=-2)
+    vals, pos = jax.lax.top_k(cand.reshape(x.shape[:-1] + (kb * LANES,)), k)
+    idx = jnp.take_along_axis(blk, pos // LANES, axis=-1) * LANES \
+        + pos % LANES
+    return vals, idx
+
+
 def draft_candidates(cfg, heads, hidden, top_k):
     """hidden: (B, d) -> candidate tokens (B, H, K) + probs (B, H, K)."""
     logits = medusa_logits(cfg, heads, hidden)     # (B, H, V)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(probs, top_k)
+    with jax.named_scope("draft_topk"):
+        vals, idx = blocked_top_k(probs, top_k)
     return idx.astype(jnp.int32), vals
 
 

@@ -5,18 +5,24 @@ TPU's block-tiling rule nor its fast-memory budget; the chip's compiler,
 installed here, checks both.  Each test compiles one kernel entry point
 for one chip of a described ``v5e:2x2`` topology, at the head shapes of
 Qwen2-0.5B (Hkv=2, hd=64) and of Vicuna-7B (Hkv=32, hd=128), and asserts
-that the program holds a Mosaic kernel.  Nothing runs on a chip.
+that the program holds a Mosaic kernel.  The Medusa draft is compiled at
+Qwen2-0.5B's vocabulary to check that no sort spans a whole row.  Nothing
+runs on a chip.
 
 The topology is described inside a fixture, never while the module is
 imported: only one process at a time may load the TPU library.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core.speculative import medusa as M
 from repro.kernels import sparse_tree as KS
 from repro.kernels import tree_attention as KT
 
@@ -101,3 +107,21 @@ def test_paged_kernels_compile_for_v5e(one_chip, entry, model, W, kv_dtype):
     else:
         _compile(lambda *a: KT.paged_cache_attention(*a, interpret=False),
                  o["q"], o["pool"], o["pool"], o["scale"], o["scale"], *walk)
+
+
+def test_draft_compiles_without_a_full_vocabulary_sort(one_chip):
+    """A wide ``lax.top_k`` compiles to a sort of the whole row on the chip;
+    the draft's blocked top-k sorts 1,187 block maxima, the 10 chosen block
+    ids and their 1,280 candidates, never the 151,936 columns."""
+    cfg = get_config("qwen2-0.5b")
+    heads = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: M.init_medusa(cfg, jax.random.PRNGKey(0))))
+    hidden = jax.ShapeDtypeStruct((1, cfg.d_model), jnp.dtype(cfg.dtype),
+                                  sharding=one_chip)
+    text = jax.jit(functools.partial(
+        M.draft_candidates, cfg, top_k=cfg.medusa_top_k)).lower(
+            heads, hidden).compile().as_text()
+    widths = {int(w) for line in text.splitlines() if " sort(" in line
+              for w in re.findall(r"\[1,4,(\d+)\]", line.split(" sort(")[0])}
+    assert sorted(widths) == [10, 1_187, 1_280]
