@@ -4,10 +4,25 @@ check, metrics.
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration in the file the entry names, its traffic in
 ``bench/traffic/<traffic>.json``, the limits its output check is held to
-in ``bench/checks/<cell>.json``, the reference its configuration names in
-``bench/references/<reference>.py`` and each metric's reader in
-``bench/metrics/<metric>.py``.  Adding a cell or a metric adds files and
-entries; nothing here changes.
+in ``bench/checks/<cell>.json``, its architecture module in
+``bench/references/<reference>.py`` (the configuration's ``reference``)
+and each metric's reader in ``bench/metrics/<metric>.py``.  Adding a
+cell, a metric or an architecture adds files and entries; nothing here
+changes.
+
+An architecture module is the one place that describes a model to the
+benchmark, and imports nothing of the program.  It provides:
+
+- ``dims(config)``: the sizes, read from the configuration file in the
+  source's own key names, with at least ``vocab``, ``d_model``,
+  ``n_layers``, ``n_heads``, ``n_kv_heads``, ``head_dim`` and
+  ``token_params`` (the weights one token multiplies through);
+- ``program_kwargs(config)``: the program's ``ModelConfig`` keywords,
+  ``arch_type`` included, as a plain dict;
+- ``make_weights(dims, keys)``: the parameters in the program's layout
+  and served type, one key of the iterator ``keys`` per leaf;
+- ``logits(params, dims, prompt, served, pad_to=, mode=)``: the plain
+  reference (``mode="f32"``) and its control (``mode="int8"``).
 
 The system under test is the program's serving path as users run it: a
 ``DecodeEngine`` with a Medusa draft strategy over a paged bfloat16 KV
@@ -92,43 +107,27 @@ def read_metrics(root: Path, entries: list, run) -> dict:
 # --------------------------------------------------------------------------
 # the system under test
 # --------------------------------------------------------------------------
-def dims_of(config: dict, traffic: dict, padded_vocab: int) -> dict:
-    """The sizes the weights and the reference need, from a configuration
-    file in the source's own key names."""
-    d = config["hidden_size"]
-    H = config["num_attention_heads"]
-    return {
-        "d_model": d, "n_layers": config["num_hidden_layers"],
-        "n_heads": H, "n_kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim", d // H),
-        "d_ff": config["intermediate_size"],
-        "vocab": config["vocab_size"], "vocab_padded": padded_vocab,
-        "qkv_bias": bool(config.get("attention_bias", False)),
-        "tied": bool(config["tie_word_embeddings"]),
-        "rms_eps": float(config["rms_norm_eps"]),
-        "rope_theta": float(config["rope_theta"]),
-        "medusa_heads": config["serving"]["medusa_heads"],
-        "dtype": config["torch_dtype"],
-        "n_out_max": int(traffic["output"]["max"]),
-    }
+def architecture(root: Path, config: dict):
+    """The architecture module a configuration names."""
+    return load_module(root / "bench" / "references"
+                       / f"{config['reference']}.py")
 
 
-def program_config(config: dict):
+def dims_of(arch, config: dict, traffic: dict, padded_vocab: int) -> dict:
+    """The architecture's sizes, with what serving and traffic add."""
+    return {**arch.dims(config), "vocab_padded": padded_vocab,
+            "medusa_heads": config["serving"]["medusa_heads"],
+            "dtype": config["torch_dtype"],
+            "n_out_max": int(traffic["output"]["max"])}
+
+
+def program_config(arch, config: dict):
     """The program's ``ModelConfig`` for a configuration file."""
     from repro.configs.base import ModelConfig
     s = config["serving"]
-    d = config["hidden_size"]
     return ModelConfig(
-        name=config["name"], arch_type="dense", source=config["source"],
-        num_layers=config["num_hidden_layers"], d_model=d,
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim", d // config["num_attention_heads"]),
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        qkv_bias=bool(config.get("attention_bias", False)),
-        rope_theta=float(config["rope_theta"]),
-        rmsnorm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
+        name=config["name"], source=config["source"],
+        **arch.program_kwargs(config),
         medusa_heads=s["medusa_heads"], medusa_top_k=s["medusa_top_k"],
         dtype=config["torch_dtype"])
 
@@ -142,9 +141,10 @@ class System:
     params: object
     heads: object
     dims: dict
+    arch: object            # the architecture module
 
 
-def build(config: dict, traffic: dict, seed: int) -> System:
+def build(config: dict, traffic: dict, seed: int, arch) -> System:
     import jax
 
     from repro.core.speculative import tree as T
@@ -154,10 +154,10 @@ def build(config: dict, traffic: dict, seed: int) -> System:
     from repro.runtime.scheduler import ContinuousScheduler
 
     s = config["serving"]
-    cfg = program_config(config)
+    cfg = program_config(arch, config)
     model = get_model(cfg)
-    dims = dims_of(config, traffic, cfg.padded_vocab)
-    params, heads = weights.build(dims, seed)
+    dims = dims_of(arch, config, traffic, cfg.padded_vocab)
+    params, heads = weights.build(arch, dims, seed)
     weights.check_layout((params, heads), jax.eval_shape(
         lambda k: (model.init_params(k), init_medusa(cfg, k)),
         jax.random.PRNGKey(0)))
@@ -173,7 +173,17 @@ def build(config: dict, traffic: dict, seed: int) -> System:
                                 chunk=s["chunk"],
                                 prefill_chunk=s["prefill_chunk"])
     spans = EngineSpans(eng, sched, sched.prefill_chunk)
-    return System(eng, sched, spans, params, heads, dims)
+    return System(eng, sched, spans, params, heads, dims, arch)
+
+
+def reseed(sys_: System, seed: int) -> None:
+    """Serve the weights of another seed; the old set is freed first, so
+    one set is on the chip at a time."""
+    sys_.engine.params = sys_.engine.heads = None
+    sys_.params = sys_.heads = None
+    gc.collect()
+    sys_.params, sys_.heads = weights.build(sys_.arch, sys_.dims, seed)
+    sys_.engine.params, sys_.engine.heads = sys_.params, sys_.heads
 
 
 def warm_up(sys_: System, seed: int) -> None:
@@ -466,7 +476,7 @@ def execute(root: Path, workload: str, seed: int, seconds: float,
     config = load_json(root / centry["file"])
     traffic = load_json(bench / "traffic" / f"{cell['traffic']}.json")
     limits = load_json(bench / "checks" / f"{workload}.json")
-    ref = load_module(bench / "references" / f"{config['reference']}.py")
+    arch = architecture(root, config)
     device = device_check(int(cell["chips"]))
     peaks = peaks_for(bench, device["kind"])
 
@@ -476,7 +486,7 @@ def execute(root: Path, workload: str, seed: int, seconds: float,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = CompileClock().install()
 
-    sys_ = build(config, traffic, seed)
+    sys_ = build(config, traffic, seed, arch)
     warm_up(sys_, seed)
     win = run_window(sys_, traffic, seed, seconds,
                      trace_dir if trace else None, clock)
@@ -487,7 +497,7 @@ def execute(root: Path, workload: str, seed: int, seconds: float,
     params, heads, dims = sys_.params, sys_.heads, sys_.dims
     del sys_, heads
     gc.collect()
-    checks = check_outputs(win, ref, params, dims, traffic, seed, limits)
+    checks = check_outputs(win, arch, params, dims, traffic, seed, limits)
     attempted = len(latency.arrived(win.requests, seconds))
     failed = sum(1 for r in latency.arrived(win.requests, seconds)
                  if not r["done"])

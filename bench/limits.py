@@ -23,7 +23,6 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import gc  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -50,34 +49,27 @@ def main(argv=None) -> int:
     ap.add_argument("--first-seed", type=int, default=1_000_003)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import harness, traffic as tm, weights
+    from bench import harness, traffic as tm
 
     spec, cell, centry = harness.find_cell(ROOT, args.workload)
     config = harness.load_json(ROOT / centry["file"])
     bench = ROOT / "bench"
     traffic = harness.load_json(bench / "traffic" / f"{cell['traffic']}.json")
-    ref = harness.load_module(bench / "references"
-                              / f"{config['reference']}.py")
+    arch = harness.architecture(ROOT, config)
     harness.device_info(int(cell["chips"]))
     import jax
     from repro.launch.compile_cache import use_compile_cache
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = harness.CompileClock().install()
-    sys_ = harness.build(config, traffic, args.first_seed)
+    sys_ = harness.build(config, traffic, args.first_seed, arch)
     harness.warm_up(sys_, args.first_seed)
     pad = harness._pad(tm.max_context(traffic))
     prog, ctrl = [], []
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
         if i:
-            # one set of weights on the chip at a time
-            sys_.engine.params = sys_.engine.heads = None
-            sys_.params = sys_.heads = None
-            gc.collect()
-            sys_.params, sys_.heads = weights.build(sys_.dims, seed)
-            sys_.engine.params = sys_.params
-            sys_.engine.heads = sys_.heads
+            harness.reseed(sys_, seed)
         win = harness.run_window(sys_, traffic, seed, args.seconds, None,
                                  clock)
         sample = harness.sample_for_check(
@@ -85,10 +77,10 @@ def main(argv=None) -> int:
         gaps_p, gaps_c = [], []
         for r in sample:
             prompt, toks = win.served[r["index"]]
-            gaps_p.append(harness.logit_gaps(ref, sys_.params, sys_.dims,
+            gaps_p.append(harness.logit_gaps(arch, sys_.params, sys_.dims,
                                              prompt, toks, pad))
             if i < args.control:
-                gaps_c.append(harness.logit_gaps(ref, sys_.params, sys_.dims,
+                gaps_c.append(harness.logit_gaps(arch, sys_.params, sys_.dims,
                                                  prompt, toks, pad,
                                                  mode="int8"))
         g = _summary(harness, gaps_p)

@@ -15,6 +15,11 @@ matmul precision.  ``mode="int8"`` is the control, the same reference
 computed one precision below the configuration's bfloat16: every linear
 layer in int8 (weights per output channel, activations per token,
 symmetric, int32 accumulation).
+
+Beside the forward pass it describes the architecture to the benchmark:
+``dims`` (its sizes and ``token_params``), ``program_kwargs`` (the
+program's ``ModelConfig`` for it) and ``make_weights`` (its weights in
+the program's layout).
 """
 from __future__ import annotations
 
@@ -23,7 +28,82 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from bench.weights import around_one, normal
+
 HI = jax.lax.Precision.HIGHEST
+
+
+def dims(config: dict) -> dict:
+    """The sizes, from a configuration file in the source's own key names,
+    and ``token_params``: the weights one token multiplies through, every
+    layer's projections and MLP and the output head over the vocabulary."""
+    d = config["hidden_size"]
+    H = config["num_attention_heads"]
+    out = {
+        "d_model": d, "n_layers": config["num_hidden_layers"],
+        "n_heads": H, "n_kv_heads": config["num_key_value_heads"],
+        "head_dim": config.get("head_dim", d // H),
+        "d_ff": config["intermediate_size"], "vocab": config["vocab_size"],
+        "qkv_bias": bool(config.get("attention_bias", False)),
+        "tied": bool(config["tie_word_embeddings"]),
+        "rms_eps": float(config["rms_norm_eps"]),
+        "rope_theta": float(config["rope_theta"]),
+    }
+    hq, hkv, hd = H, out["n_kv_heads"], out["head_dim"]
+    per_layer = d * hd * (2 * hq + 2 * hkv) + 3 * d * out["d_ff"]
+    out["token_params"] = out["n_layers"] * per_layer + d * out["vocab"]
+    return out
+
+
+def program_kwargs(config: dict) -> dict:
+    """Keyword arguments of the program's ``ModelConfig`` for this
+    architecture; the harness adds name, source, serving settings and
+    dtype."""
+    d = dims(config)
+    return {"arch_type": "dense", "num_layers": d["n_layers"],
+            "d_model": d["d_model"], "num_heads": d["n_heads"],
+            "num_kv_heads": d["n_kv_heads"], "head_dim": d["head_dim"],
+            "d_ff": d["d_ff"], "vocab_size": d["vocab"],
+            "qkv_bias": d["qkv_bias"], "rope_theta": d["rope_theta"],
+            "rmsnorm_eps": d["rms_eps"], "tie_embeddings": d["tied"]}
+
+
+def make_weights(dims: dict, keys) -> dict:
+    """The model's parameters in the program's layout and ``dims["dtype"]``,
+    each leaf drawn from the next key of the iterator ``keys``.
+    Projections are N(0, 1/fan_in); embeddings N(0, 0.02^2); qkv biases
+    N(0, 0.1^2); norm scales near 1."""
+    dt = jnp.dtype(dims["dtype"])
+    d, L, f = dims["d_model"], dims["n_layers"], dims["d_ff"]
+    hd, hq, hkv = dims["head_dim"], dims["n_heads"], dims["n_kv_heads"]
+    vp = dims["vocab_padded"]
+    attn = {
+        "wq": normal(next(keys), (L, d, hq * hd), d ** -0.5, dt),
+        "wk": normal(next(keys), (L, d, hkv * hd), d ** -0.5, dt),
+        "wv": normal(next(keys), (L, d, hkv * hd), d ** -0.5, dt),
+        "wo": normal(next(keys), (L, hq * hd, d), (hq * hd) ** -0.5, dt),
+    }
+    if dims["qkv_bias"]:
+        attn["bq"] = normal(next(keys), (L, hq * hd), 0.1, dt)
+        attn["bk"] = normal(next(keys), (L, hkv * hd), 0.1, dt)
+        attn["bv"] = normal(next(keys), (L, hkv * hd), 0.1, dt)
+    params = {
+        "embed": normal(next(keys), (vp, d), 0.02, dt),
+        "layers": {
+            "ln1": around_one(next(keys), (L, d), dt),
+            "ln2": around_one(next(keys), (L, d), dt),
+            "attn": attn,
+            "mlp": {
+                "w_gate": normal(next(keys), (L, d, f), d ** -0.5, dt),
+                "w_up": normal(next(keys), (L, d, f), d ** -0.5, dt),
+                "w_down": normal(next(keys), (L, f, d), f ** -0.5, dt),
+            },
+        },
+        "ln_f": around_one(next(keys), (d,), dt),
+    }
+    if not dims["tied"]:
+        params["lm_head"] = normal(next(keys), (d, vp), d ** -0.5, dt)
+    return params
 
 
 def _mm(x, w):

@@ -32,18 +32,11 @@ def paged_tree_attention(dims: dict, width: int, mask_nnz: int, ctxs,
     return flops, bytes_
 
 
-def matmul_params(dims: dict) -> int:
-    """Weights a token multiplies through: every layer's projections and
-    MLP, and the output head over the vocabulary."""
-    d, f, L = dims["d_model"], dims["d_ff"], dims["n_layers"]
-    hq, hkv, hd = dims["n_heads"], dims["n_kv_heads"], dims["head_dim"]
-    per_layer = d * hd * (2 * hq + 2 * hkv) + 3 * d * f
-    return L * per_layer + d * dims["vocab"]
-
-
 def token_flops(dims: dict, ctx: int) -> int:
-    """Forward flops of one token at cached context ``ctx``: 2 per weight,
-    plus attention over ``ctx`` keys (QK and PV) in every layer."""
+    """Forward flops of one token at cached context ``ctx``: 2 per weight
+    it multiplies through (``dims["token_params"]``, counted by the
+    architecture module), plus attention over ``ctx`` keys (QK and PV) in
+    every layer."""
     attn = 4 * dims["head_dim"] * dims["n_heads"] * int(ctx) \
         * dims["n_layers"]
-    return 2 * matmul_params(dims) + attn
+    return 2 * dims["token_params"] + attn

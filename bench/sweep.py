@@ -44,7 +44,8 @@ def main(argv=None) -> int:
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = harness.CompileClock().install()
-    sys_ = harness.build(config, traffic, args.seed)
+    sys_ = harness.build(config, traffic, args.seed,
+                         harness.architecture(ROOT, config))
     harness.warm_up(sys_, args.seed)
     T = args.seconds
     for rate in args.rates:

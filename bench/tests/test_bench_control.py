@@ -17,16 +17,15 @@ def served(tmp_path_factory):
     config = harness.load_json(root / centry["file"])
     traffic = harness.load_json(root / "bench/traffic/tiny-closed.json")
     limits = harness.load_json(root / "bench/checks/tiny.closed.json")
-    ref = harness.load_module(root / "bench/references/dense_gqa.py")
-    sys_ = harness.build(config, traffic, 11)
+    ref = harness.architecture(root, config)
+    sys_ = harness.build(config, traffic, 11, ref)
     harness.warm_up(sys_, 11)
     out = []
     for seed in (1_000_003, 1_007_922, 1_015_841):
-        p, h = harness.weights.build(sys_.dims, seed)
-        sys_.engine.params, sys_.engine.heads = p, h
+        harness.reseed(sys_, seed)
         win = harness.run_window(sys_, traffic, seed, 2.0, None,
                                  CompileClock())
-        out.append((win, p, seed))
+        out.append((win, sys_.params, seed))
     return sys_.dims, traffic, limits, ref, out
 
 

@@ -13,7 +13,8 @@ METRICS = Path(__file__).resolve().parents[1] / "metrics"
 MS = 1_000_000          # ns
 
 DIMS = {"d_model": 896, "n_layers": 2, "n_heads": 14, "n_kv_heads": 2,
-        "head_dim": 64, "d_ff": 4864, "vocab": 151936}
+        "head_dim": 64, "d_ff": 4864, "vocab": 151936,
+        "token_params": 165_953_536}    # dense_gqa.dims' count of these
 PEAKS = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
 
 

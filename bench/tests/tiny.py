@@ -30,6 +30,42 @@ TRAFFIC = {
 # most 8.1e-5, the int8 control at least 2.9e-4.
 LIMITS = {"max_logit_gap": 0.05, "mean_logit_gap": 1.6e-4,
           "min_tokens_checked": 40}
+# an architecture the harness has never seen, added as a file: dense_gqa's
+# under another name, recording each call in ``tiny_recorded.calls``
+RECORDED_ARCH = '''
+from pathlib import Path
+
+from bench.harness import load_module
+
+DENSE = load_module(Path(__file__).with_name("dense_gqa.py"))
+CALLS = Path(__file__).with_suffix(".calls")
+
+
+def _record(name):
+    with open(CALLS, "a") as f:
+        f.write(name + "\\n")
+
+
+def dims(config):
+    _record("dims")
+    return DENSE.dims(config)
+
+
+def program_kwargs(config):
+    _record("program_kwargs")
+    return DENSE.program_kwargs(config)
+
+
+def make_weights(dims, keys):
+    _record("make_weights")
+    return DENSE.make_weights(dims, keys)
+
+
+def logits(params, dims, prompt, served, *, pad_to, mode="f32"):
+    _record("logits." + mode)
+    return DENSE.logits(params, dims, prompt, served, pad_to=pad_to,
+                        mode=mode)
+'''
 
 
 def make_root(tmp: Path) -> Path:
@@ -50,6 +86,18 @@ def make_root(tmp: Path) -> Path:
         spec["workloads"].append({"name": f"tiny.{mix}", "config": "tiny",
                                   "traffic": f"tiny-{mix}", "chips": 1,
                                   "why": "test"})
+    (tmp / "bench/references/tiny_recorded.py").write_text(RECORDED_ARCH)
+    (tmp / "bench/configs/tiny-arch.json").write_text(
+        json.dumps(dict(cfg, name="tiny-arch", reference="tiny_recorded")))
+    (tmp / "bench/checks/tiny-arch.closed.json").write_text(
+        json.dumps(LIMITS))
+    spec["configs"].append({"name": "tiny-arch", "source": "test",
+                            "file": "bench/configs/tiny-arch.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "tiny-arch.closed",
+                              "config": "tiny-arch",
+                              "traffic": "tiny-closed", "chips": 1,
+                              "why": "test"})
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp
 
