@@ -18,6 +18,23 @@ MAMBA2 = "mamba2"
 SLSTM = "slstm"
 MLSTM = "mlstm"
 
+# Attention kinds of ``ModelConfig.layer_types`` (the source configs' names).
+SLIDING = "sliding_attention"
+FULL = "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (transformers' ``rope_type: yarn``): the
+    frequency table is interpolated by ``factor`` below the band set by
+    ``beta_fast``/``beta_slow`` rotations over ``original_max_position``
+    tokens, and cos/sin are multiplied by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -65,8 +82,16 @@ class ModelConfig:
     num_frontend_tokens: int = 0        # patch/frame embeddings per sample
 
     # --- long-context ------------------------------------------------------
-    sliding_window: int = 0             # 0 = full attention; >0 = window size
-                                        # used for the long_500k decode shape
+    sliding_window: int = 0             # 0 = full attention; >0 = window size:
+                                        # of the "sliding_attention" layers
+                                        # when ``layer_types`` is set, else a
+                                        # hint for the long_500k decode shape
+    # Per-layer attention kinds, one entry per layer: "sliding_attention"
+    # (window ``sliding_window``, plain RoPE at ``rope_theta``) or
+    # "full_attention" (no window, RoPE scaled by ``rope_yarn`` when set).
+    # None => every layer alike, full attention, plain RoPE.
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_yarn: Optional[Yarn] = None  # full-attention layers' YaRN scaling
 
     # --- speculative decoding (Ghidorah) ----------------------------------
     medusa_heads: int = 4               # number of drafting heads
@@ -90,10 +115,35 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # plain values from a configuration file: kinds as a tuple, YaRN's
+        # parameters as a mapping of ``Yarn``'s fields
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if isinstance(self.rope_yarn, dict):
+            object.__setattr__(self, "rope_yarn", Yarn(**self.rope_yarn))
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, (
             f"{self.name}: heads {self.num_heads} not divisible by kv {self.num_kv_heads}")
         if self.num_experts:
             assert 0 < self.experts_per_token <= self.num_experts
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.num_layers or \
+                    not kinds <= {SLIDING, FULL}:
+                raise ValueError(
+                    f"{self.name}: layer_types needs {self.num_layers} "
+                    f"entries of {SLIDING!r} / {FULL!r}, got "
+                    f"{self.layer_types}")
+            if SLIDING in kinds and self.sliding_window <= 0:
+                raise ValueError(f"{self.name}: sliding_attention layers "
+                                 "need sliding_window > 0")
+
+    def layer_windows(self) -> Optional[Tuple[int, ...]]:
+        """Each layer's attention window (0 = full), or None where every
+        layer attends alike (no ``layer_types``)."""
+        if self.layer_types is None:
+            return None
+        return tuple(self.sliding_window if t == SLIDING else 0
+                     for t in self.layer_types)
 
     # ------------------------------------------------------------------
     @property

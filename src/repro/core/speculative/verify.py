@@ -71,7 +71,7 @@ class SpecState:
 
 
 def spec_step(model, params, heads, tree, state: SpecState, *, backend="ref",
-              tree_kernel="dense", active=None):
+              tree_kernel="dense", active=None, return_counts=False):
     """One Ghidorah speculative decoding step, batched over sequences.
 
     Each sequence accepts its own chain length; the commit is a per-sequence
@@ -85,6 +85,10 @@ def spec_step(model, params, heads, tree, state: SpecState, *, backend="ref",
     The chunk driver uses this to stop finished / capacity-exhausted / free
     slots from writing into their cache rows while the rest of the batch
     keeps decoding (runtime/scheduler.py evicts them at the chunk boundary).
+
+    ``return_counts`` appends the verify's expert counts (int32 (3,):
+    1 forward, picks, distinct experts touched summed over layers; None
+    for a model without experts) as a fourth result.
     """
     cfg = model.cfg
     # named scopes group the step's device ops by phase in a profile
@@ -131,6 +135,8 @@ def spec_step(model, params, heads, tree, state: SpecState, *, backend="ref",
                             child_shift, 0)
         emitted = jnp.where(idx == (acc["n_accept"] - 1)[:, None],
                             acc["bonus"][:, None], emitted)
+    if return_counts:
+        return new_state, emitted, n_accept, extras.get("moe")
     return new_state, emitted, n_accept
 
 

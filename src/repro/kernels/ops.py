@@ -13,11 +13,27 @@ import jax.numpy as jnp
 from repro.kernels import sparse_tree as _sparse
 from repro.kernels import tree_attention as _tree
 
+# the largest m-tile of the grouped matmul: 128 rows, one MXU pass
+_GMM_TM = 128
+
 
 def _interpret() -> bool:
     """True on the CPU backend, False on a TPU (and anywhere else, where
     the Mosaic lowering then fails loudly instead of interpreting)."""
     return jax.default_backend() == "cpu"
+
+
+def _window_lo(q_pos, window):
+    """Exclusive lower bound of the keys each query sees: ``q_pos -
+    window`` (keys ``k > q_pos - window``: the query and the ``window - 1``
+    before it), or -1 where there is no window.  ``window`` is a Python
+    int (one for the whole stack) or a traced per-layer scalar of the
+    layer scan (0 on full-attention layers)."""
+    if isinstance(window, jax.Array):
+        return jnp.where(window > 0, q_pos - window, -1)
+    if window:
+        return q_pos - window
+    return jnp.full_like(q_pos, -1)
 
 
 def tree_attention(q, ck, cv, k_new, v_new, key_pos, pos, tree_depth,
@@ -32,10 +48,7 @@ def tree_attention(q, ck, cv, k_new, v_new, key_pos, pos, tree_depth,
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     key_pos_b = jnp.broadcast_to(key_pos, (B, ck.shape[1]))
     q_pos = pos_b[:, None] + tree_depth[None, :].astype(jnp.int32)  # (B, W)
-    if window:
-        lo = q_pos - window
-    else:
-        lo = jnp.full_like(q_pos, -1)
+    lo = _window_lo(q_pos, window)
     kwargs = {"interpret": _interpret()}
     if block_s:
         kwargs["block_s"] = block_s
@@ -55,20 +68,22 @@ def _pool_scales(pool_k, scale_k, scale_v):
 
 def paged_tree_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                          key_pos, pos, tree_depth, tree_mask, *,
-                         scale_k=None, scale_v=None):
+                         scale_k=None, scale_v=None, window=0):
     """Paged-cache verification path (models/attention.py, paged engines).
 
     pool_k/pool_v are ONE layer's shared page pool ``(n_pages + 1, ps,
     Hkv, hd)`` (trash page last); block_table/key_pos/pos are the
     per-sequence rows.  ``scale_k/scale_v (n_pages + 1, Hkv)`` are the
-    int8 pool's per-page dequant scales (None = float pool).  Windowed
-    attention is dense-only (the ring IS the window), so there is no
-    ``window`` here.
+    int8 pool's per-page dequant scales (None = float pool).  ``window``
+    is the layer's attention window (0 = full; a traced per-layer scalar
+    in a stack with ``layer_types``): cached keys at or below ``q_pos -
+    window`` are masked (``_window_lo``).  The walk still visits every
+    page the row holds.
     """
     B = q.shape[0]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q_pos = pos_b[:, None] + tree_depth[None, :].astype(jnp.int32)  # (B, W)
-    lo = jnp.full_like(q_pos, -1)
+    lo = _window_lo(q_pos, window)
     sk, sv = _pool_scales(pool_k, scale_k, scale_v)
     return _tree.paged_tree_attention(q, pool_k, pool_v, sk, sv, k_new,
                                       v_new, block_table, key_pos, q_pos,
@@ -76,15 +91,17 @@ def paged_tree_attention(q, pool_k, pool_v, k_new, v_new, block_table,
 
 
 def paged_cache_attention(q, pool_k, pool_v, block_table, key_pos, pos,
-                          tree_depth, *, scale_k=None, scale_v=None):
+                          tree_depth, *, scale_k=None, scale_v=None,
+                          window=0):
     """Cache-only half of the paged verify split (``tree_kernel=sparse``):
-    the quantized page walk WITHOUT the tree block.  Returns ``(o, m, l)``
+    the quantized page walk WITHOUT the tree block, masked by the layer's
+    ``window`` as ``paged_tree_attention`` is.  Returns ``(o, m, l)``
     merge partials; the caller merges them with the
     ``sparse_tree_attention_partial`` tree half."""
     B = q.shape[0]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q_pos = pos_b[:, None] + tree_depth[None, :].astype(jnp.int32)  # (B, W)
-    lo = jnp.full_like(q_pos, -1)
+    lo = _window_lo(q_pos, window)
     sk, sv = _pool_scales(pool_k, scale_k, scale_v)
     return _tree.paged_cache_attention(q, pool_k, pool_v, sk, sv,
                                        block_table, key_pos, q_pos, lo,
@@ -117,3 +134,43 @@ def sparse_tree_attention_partial(q, k_new, v_new, tree_mask):
     ``paged_cache_attention`` page walk by the caller)."""
     return _sparse.sparse_tree_attention_partial(q, k_new, v_new, tree_mask,
                                                  interpret=_interpret())
+
+
+def _expert_matmul_impl() -> str:
+    """The grouped matmul the platform serves with: the Pallas kernel on a
+    TPU, ``lax.ragged_dot`` elsewhere (the CPU runs Pallas only in its
+    interpreter, a test tool)."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def grouped_matmul(lhs, rhs, group_sizes, layer):
+    """``lhs (m, k)`` rows sorted by group, ``rhs (L, G, k, n)`` L stacked
+    sets of G groups' weights, ``group_sizes (G,)`` int32 summing to m ->
+    ``(m, n)`` in ``lhs``'s dtype, row i multiplied by the weights of the
+    group of set ``layer`` (a traced index) it falls in.
+
+    On a TPU (``_expert_matmul_impl()``) it is megablox's grouped matmul
+    over the whole stack seen as L*G groups, of which only set
+    ``layer``'s hold rows: the grid visits each (m-tile, group) pair that
+    holds rows, and each visit reads the whole ``(k, n)`` of its group
+    from the stack in place, so a group with no rows is never read and no
+    slice of the stack is made.  Rows are padded to a multiple of the
+    m-tile (128, or m rounded up to 16 when m is smaller); padding belongs
+    to no group and is dropped.  Elsewhere it is
+    ``ref.grouped_matmul_ref`` on ``rhs[layer]``."""
+    m, k = lhs.shape
+    if _expert_matmul_impl() == "ref":
+        from repro.kernels import ref as _ref
+        return _ref.grouped_matmul_ref(lhs, rhs[layer], group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
+    L, G, _, n = rhs.shape
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((L * G,), jnp.int32), group_sizes.astype(jnp.int32),
+        (layer * G,))
+    tm = _GMM_TM if m >= _GMM_TM else -(-m // 16) * 16
+    mp = -(-m // tm) * tm
+    x = jnp.pad(lhs, ((0, mp - m), (0, 0))) if mp != m else lhs
+    out = _gmm(x, rhs.reshape(L * G, k, n), sizes,
+               preferred_element_type=lhs.dtype, tiling=(tm, k, n),
+               interpret=_interpret())
+    return out[:m]

@@ -100,3 +100,12 @@ def sparse_tree_attention_partial_ref(q, k_new, v_new, tree_mask):
     scale = q.shape[-1] ** -0.5
     return cm.gqa_attend_partial(q, k_new, v_new, tree_mask[None, None],
                                  scale)
+
+
+def grouped_matmul_ref(lhs, rhs, group_sizes):
+    """``lhs (m, k)`` rows sorted by group times their group's ``rhs (G,
+    k, n)``, float32 accumulation, in ``lhs``'s dtype (``lax.ragged_dot``:
+    rows past ``sum(group_sizes)`` come out zero)."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
-from repro.runtime.cache import batched_decode_mask, prefill_mask
+from repro.runtime.cache import batched_decode_mask, in_window, prefill_mask
 
 
 def attn_init(cfg, rng):
@@ -41,8 +41,10 @@ def _dt(cfg):
     return jnp.dtype(cfg.dtype)
 
 
-def _qkv(cfg, p, x, positions):
-    """x: (B, S, d) -> roped q (B,S,Hq,hd), k (B,S,Hkv,hd), v (B,S,Hkv,hd)."""
+def _qkv(cfg, p, x, positions, rope=None):
+    """x: (B, S, d) -> roped q (B,S,Hq,hd), k (B,S,Hkv,hd), v (B,S,Hkv,hd).
+    ``rope``: the layer's rotary row (``common.rope_tables``), else the
+    plain table at ``cfg.rope_theta``."""
     B, S, _ = x.shape
     hd = cfg.head_dim
     q = x @ p["wq"]
@@ -56,8 +58,8 @@ def _qkv(cfg, p, x, positions):
     if cfg.qk_norm:
         q = cm.rmsnorm(q, p["q_norm"], cfg.rmsnorm_eps)
         k = cm.rmsnorm(k, p["k_norm"], cfg.rmsnorm_eps)
-    q = cm.apply_rope(q, positions, cfg.rope_theta)
-    k = cm.apply_rope(k, positions, cfg.rope_theta)
+    q = cm.apply_rope(q, positions, cfg.rope_theta, rope)
+    k = cm.apply_rope(k, positions, cfg.rope_theta, rope)
     return q, k, v
 
 
@@ -65,16 +67,19 @@ BLOCKED_PREFILL_THRESHOLD = 4096      # S above which prefill uses tiling
 PREFILL_BLOCK = 1024
 
 
-def attn_prefill(cfg, p, x, *, start_pos=0, window=0, causal=True):
+def attn_prefill(cfg, p, x, *, start_pos=0, window=0, causal=True,
+                 rope=None):
     """Full-sequence (optionally causal/windowed) attention.  Returns
     (out, (k, v)) — k/v are the rope'd cache entries for [start, start+S).
+    ``window`` may be a traced per-layer scalar and ``rope`` the layer's
+    rotary row (a stack with ``layer_types``).
 
     Long sequences use the blocked online-softmax path (§Perf hillclimb A2):
     the naive form materializes (B, H, S, S) scores — at 32k prefill that
     single tensor dominates HBM traffic and the TP collectives."""
     B, S, _ = x.shape
     positions = jnp.arange(S, dtype=jnp.int32)[None, :] + start_pos
-    q, k, v = _qkv(cfg, p, x, positions)
+    q, k, v = _qkv(cfg, p, x, positions, rope)
     if causal and S >= BLOCKED_PREFILL_THRESHOLD and \
             S % PREFILL_BLOCK == 0:
         o = _blocked_causal_attend(q, k, v, cfg.head_dim ** -0.5,
@@ -108,9 +113,8 @@ def _blocked_causal_attend(q, k, v, scale, *, window=0, block=1024):
         def kv_step(carry, kv_blk):
             j, kj, vj = kv_blk
             kpos = j * block + base
-            ok = kpos[None, :] <= qpos[:, None]
-            if window:
-                ok &= kpos[None, :] > qpos[:, None] - window
+            ok = in_window(kpos[None, :] <= qpos[:, None], kpos[None, :],
+                           qpos[:, None], window)
             o, m, l = cm.gqa_attend_partial(qi, kj, vj, ok[None, None], scale)
             return (cm.merge_partials_carry(carry, (o, m, l))), None
 
@@ -162,7 +166,7 @@ def cross_kv_init(cfg, p, enc_out):
 
 def attn_verify(cfg, p, x, *, ck, cv, key_pos, pos, tree_depth, tree_mask,
                 window=0, backend="ref", block_table=None,
-                scale_k=None, scale_v=None, tree_kernel="dense"):
+                scale_k=None, scale_v=None, tree_kernel="dense", rope=None):
     """Tree-verification attention over W draft tokens (decode = W=1 case).
 
     x: (B, W, d); tree_depth: (W,) node depth (0 = first new token);
@@ -184,12 +188,18 @@ def attn_verify(cfg, p, x, *, ck, cv, key_pos, pos, tree_depth, tree_mask,
     merged by the Eq.-1 online-softmax rule; other layouts/backends fall
     back to their fused path (the split exists for the paged walk).  The
     mask math is layout-agnostic: ``key_pos`` is already logical.
+    ``window`` masks keys at or below ``q_pos - window`` on every path (a
+    Python int, or the layer's traced window in a stack with
+    ``layer_types``): cached keys by position, fresh tree keys by depth
+    (a chunked-prefill piece is a chain longer than a small window).
     Returns (out (B, W, d), (k_new, v_new)) — fresh KVs NOT yet committed.
     """
     B, W, _ = x.shape
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos_b[:, None] + tree_depth[None, :]           # (B, W)
-    q, k_new, v_new = _qkv(cfg, p, x, positions)
+    q, k_new, v_new = _qkv(cfg, p, x, positions, rope)
+    tree_mask = in_window(tree_mask, tree_depth[None, :],
+                          tree_depth[:, None], window)
     scale = cfg.head_dim ** -0.5
 
     if block_table is not None and backend == "pallas":
@@ -197,14 +207,15 @@ def attn_verify(cfg, p, x, *, ck, cv, key_pos, pos, tree_depth, tree_mask,
         if tree_kernel == "sparse":
             cache_part = kops.paged_cache_attention(
                 q, ck, cv, block_table, key_pos, pos_b, tree_depth,
-                scale_k=scale_k, scale_v=scale_v)
+                scale_k=scale_k, scale_v=scale_v, window=window)
             tree_part = kops.sparse_tree_attention_partial(
                 q, k_new, v_new, tree_mask)
             o = cm.merge_partials([cache_part, tree_part]).astype(x.dtype)
         else:
             o = kops.paged_tree_attention(
                 q, ck, cv, k_new, v_new, block_table, key_pos, pos_b,
-                tree_depth, tree_mask, scale_k=scale_k, scale_v=scale_v)
+                tree_depth, tree_mask, scale_k=scale_k, scale_v=scale_v,
+                window=window)
     else:
         if block_table is not None:
             from repro.runtime.cache import gather_pages_dequant

@@ -6,8 +6,11 @@ model stacks can ``lax.scan`` over layers.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30  # mask value (finite: avoids NaN from (-inf) - (-inf))
 
@@ -41,13 +44,63 @@ def rope_freqs(head_dim, theta):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_freqs(head_dim, theta, yarn):
+    """YaRN's inverse frequencies (transformers' ``_compute_yarn_parameters``
+    with truncation), as float64 NumPy: with ``corr(r) = d ln(L0 / (2 pi
+    r)) / (2 ln theta)``, ``low = floor(corr(beta_fast))`` and ``high =
+    ceil(corr(beta_slow))``, frequency i blends ``theta_i / factor`` and
+    ``theta_i`` by ``ramp_i = clip((i - low) / (high - low), 0, 1)``."""
+    d = head_dim
+    inv = 1.0 / (float(theta) ** (np.arange(0, d, 2, dtype=np.float64) / d))
+
+    def corr(rot):
+        return d * math.log(yarn.original_max_position / (2 * math.pi * rot)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_tables(cfg):
+    """Per-layer rotary operands ``(inv_freq (L, hd/2), scale (L,))`` of a
+    stack with ``layer_types``: plain RoPE at ``rope_theta`` on sliding
+    layers, YaRN (``rope_yarn``) on full ones; ``scale`` multiplies cos and
+    sin."""
+    from repro.configs.base import FULL
+    plain = 1.0 / (float(cfg.rope_theta) ** (
+        np.arange(0, cfg.head_dim, 2, dtype=np.float64) / cfg.head_dim))
+    rows, scales = [], []
+    for kind in cfg.layer_types:
+        if kind == FULL and cfg.rope_yarn is not None:
+            rows.append(yarn_freqs(cfg.head_dim, cfg.rope_theta,
+                                   cfg.rope_yarn))
+            scales.append(cfg.rope_yarn.attention_factor)
+        else:
+            rows.append(plain)
+            scales.append(1.0)
+    return (jnp.asarray(np.stack(rows), jnp.float32),
+            jnp.asarray(scales, jnp.float32))
+
+
+def apply_rope(x, positions, theta, rope=None):
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  ``rope``
+    = ``(inv_freq (hd/2,), scale ())``, one layer's row of
+    ``rope_tables``, replaces the plain table at ``theta``."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if rope is None:
+        freqs, scale = rope_freqs(hd, theta), None      # (hd/2,)
+    else:
+        freqs, scale = rope
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                 # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

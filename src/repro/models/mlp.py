@@ -1,9 +1,20 @@
-"""SwiGLU MLP and GShard-style top-k MoE with grouped one-hot dispatch.
+"""SwiGLU MLP and top-k MoE: a dropless sorted dispatch for serving and
+GShard-style grouped one-hot dispatch for training.
 
-MoE dispatch uses the capacity-factor one-hot einsum form (GShard/MaxText
-style): it lowers to MXU-friendly einsums whose expert dimension shards
-cleanly on the `model` mesh axis (all-to-all appears in SPMD HLO).  Dropped
-tokens (over capacity) pass through on the residual path — standard.
+Serving (``moe_dropless``) computes every pick: picks are sorted by
+expert and run through a grouped matmul (``kernels/ops.grouped_matmul``)
+that reads only the routed experts' weights, so a prefill piece, an
+extension, a tree verify and a one-token decode give the same result for
+a token whatever else is in the call.
+
+Training (``moe_apply``) uses the capacity-factor one-hot einsum form
+(GShard/MaxText style): it lowers to MXU-friendly einsums whose expert
+dimension shards cleanly on the `model` mesh axis (all-to-all appears in
+SPMD HLO).  Dropped tokens (over capacity) pass through on the residual
+path — standard.
+
+Both route alike (``route``): softmax over the experts in float32, top-k,
+the k gates renormalized to sum to 1.
 """
 from __future__ import annotations
 
@@ -47,6 +58,58 @@ def moe_init(cfg, rng):
     }
 
 
+def route(cfg, p, x):
+    """x: (..., d) -> (probs (..., E), gates (..., K), experts (..., K)):
+    softmax over the experts in float32 at full matmul precision, the top
+    ``experts_per_token``, their gates renormalized to sum to 1."""
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.experts_per_token)
+    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    return probs, gates, experts
+
+
+# the experts' weights, stacked (E, ...) per layer
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def moe_dropless(cfg, p, x, stacks):
+    """x: (B, S, d) -> (out (B, S, d), counts int32 (2,)): every token's
+    every pick computed (no capacity, no drop).
+
+    The B*S*K picks are sorted by expert; one grouped matmul per weight
+    (gate, up, down) runs each expert's rows against that expert's
+    weights alone, so an expert no pick chose is never read.  ``p``
+    holds the layer's router and its index ``p["layer"]`` into
+    ``stacks``, every layer's ``(L, E, ...)`` expert weights, which are
+    read in place (a scanned slice of them would copy every expert of the
+    layer first).  ``counts`` = (picks, distinct experts touched), what
+    the call read, for the serving counters
+    (``runtime/telemetry.ExpertCounters``)."""
+    from repro.kernels import ops as kops
+    B, S, d = x.shape
+    E, K = cfg.num_experts, cfg.experts_per_token
+    layer = p["layer"]
+    xt = x.reshape(B * S, d)
+    with jax.named_scope("moe.route"):
+        _, gates, experts = route(cfg, p, xt)                  # (T, K)
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)                 # by expert
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        rows = jnp.take(xt, order // K, axis=0)                # (T*K, d)
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(kops.grouped_matmul(rows, stacks["w_gate"], sizes,
+                                            layer))
+        h = h * kops.grouped_matmul(rows, stacks["w_up"], sizes, layer)
+        y = kops.grouped_matmul(h, stacks["w_down"], sizes, layer)
+    picks = jnp.zeros_like(y).at[order].set(y).reshape(B * S, K, d)
+    out = jnp.einsum("tkd,tk->td", picks.astype(jnp.float32), gates)
+    counts = jnp.stack([jnp.int32(flat.shape[0]),
+                        jnp.sum(sizes > 0).astype(jnp.int32)])
+    return out.astype(x.dtype).reshape(B, S, d), counts
+
+
 def moe_apply(cfg, p, x, *, capacity_factor=1.25, group_size=256):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
 
@@ -64,20 +127,16 @@ def moe_apply(cfg, p, x, *, capacity_factor=1.25, group_size=256):
     while T % g:                                   # largest divisor <= group_size
         g -= 1
     G = T // g
-    # small groups (decode / tree-verify) run DROPLESS (cap = g) so cached
-    # serving is bit-consistent with prefill; large training groups use the
-    # standard capacity factor (dropped tokens ride the residual).
+    # small groups run with cap = g (nothing dropped); larger groups use
+    # the standard capacity factor (dropped tokens ride the residual).
+    # Serving never comes here: it routes dropless (``moe_dropless``).
     if g <= 32:
         cap = g
     else:
         cap = max(K, int(g * K / E * capacity_factor))
     xg = x.reshape(G, g, d)
 
-    logits = xg.astype(jnp.float32) @ p["router"]              # (G,g,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)              # (G,g,K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)      # renormalize
+    probs, gate_vals, gate_idx = route(cfg, p, xg)             # (G,g,E|K)
 
     # rank of each (token, k) choice inside its expert's capacity buffer
     onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)      # (G,g,K,E)

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from repro.models import common as cm
 from repro.models.attention import attn_decode, attn_init, attn_prefill, attn_verify
-from repro.models.mlp import mlp_apply, mlp_init, moe_apply, moe_init
+from repro.models.mlp import (EXPERT_WEIGHTS, mlp_apply, mlp_init, moe_apply,
+                              moe_dropless, moe_init)
 from repro.runtime.cache import (Cache, KVCache, PagedKVCache, _ring_match,
                                  init_kv_cache, kv_commit, paged_kv_write)
 
@@ -48,9 +49,62 @@ def init_params(cfg, rng):
 
 
 def _mix(cfg, lp, h):
+    """Training MLP: (out, aux loss); experts with capacity drops."""
     if cfg.num_experts:
         return moe_apply(cfg, lp["moe"], h)
     return mlp_apply(cfg, lp["mlp"], h), jnp.zeros((), jnp.float32)
+
+
+def _serve_mix(cfg, lp, h, stacks):
+    """Serving MLP: (out, counts) — experts dropless, counts (picks,
+    distinct experts touched) per layer; None for a dense MLP.  ``stacks``:
+    the experts' weights of every layer (``_serve_layers``)."""
+    if cfg.num_experts:
+        return moe_dropless(cfg, lp["moe"], h, stacks)
+    return mlp_apply(cfg, lp["mlp"], h), None
+
+
+def _serve_layers(cfg, layers):
+    """Serving's layer operands: (the scanned per-layer params, the
+    experts' ``(L, E, ...)`` weight stacks or None).  A sparse-expert
+    stack scans each layer's index in place of its experts, which the
+    grouped matmul reads from the stacks in place: a scanned slice of them
+    would copy all E experts of the layer every step."""
+    if not cfg.num_experts:
+        return layers, None
+    moe = {k: v for k, v in layers["moe"].items() if k not in EXPERT_WEIGHTS}
+    moe["layer"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    return dict(layers, moe=moe), {k: layers["moe"][k]
+                                   for k in EXPERT_WEIGHTS}
+
+
+def _layer_kinds(cfg):
+    """Per-layer operands the layer scan carries beside the weights in a
+    stack with ``layer_types``: each layer's window (0 = full) and rotary
+    row (``common.rope_tables``).  None where every layer is alike — the
+    scan then carries nothing more and the window is the cache's."""
+    wins = cfg.layer_windows()
+    if wins is None:
+        return None
+    inv, scale = cm.rope_tables(cfg)
+    return {"window": jnp.asarray(wins, jnp.int32), "inv_freq": inv,
+            "rope_scale": scale}
+
+
+def _kind_args(kinds, window):
+    """(window, rope) of one layer: its scanned row, else the stack's."""
+    if kinds is None:
+        return window, None
+    return kinds["window"], (kinds["inv_freq"], kinds["rope_scale"])
+
+
+def _moe_counts(counts):
+    """Per-layer (picks, touched) of one forward -> int32 (3,): (1
+    forward, picks, touched) summed over layers; None for dense stacks."""
+    if counts is None:
+        return None
+    return jnp.concatenate([jnp.ones((1,), jnp.int32),
+                            jnp.sum(counts, axis=0)])
 
 
 def _logits(cfg, params, x):
@@ -73,22 +127,34 @@ def prefill(cfg, params, tokens=None, embeds=None, *, cache=None, window=0,
     x = embed_tokens(cfg, params, tokens) if embeds is None else embeds
     B, S, _ = x.shape
     eff_window = window                 # 0 = full attention; engine decides
+    kinds = _layer_kinds(cfg)
+    # a cache means serving: experts dropless, as in verify
+    layers, stacks = _serve_layers(cfg, params["layers"]) if return_cache \
+        else (params["layers"], None)
 
-    def body(xc, lp):
+    def body(xc, xs):
+        lp, kind = xs
+        win, rope = _kind_args(kind, eff_window)
         a, (k, v) = attn_prefill(cfg, lp["attn"],
                                  cm.rmsnorm(xc, lp["ln1"], cfg.rmsnorm_eps),
-                                 window=eff_window)
+                                 window=win, rope=rope)
         xc = xc + a
-        m, aux = _mix(cfg, lp, cm.rmsnorm(xc, lp["ln2"], cfg.rmsnorm_eps))
+        h = cm.rmsnorm(xc, lp["ln2"], cfg.rmsnorm_eps)
+        m, aux = _serve_mix(cfg, lp, h, stacks) if return_cache else \
+            _mix(cfg, lp, h)
         kv_out = (k, v) if return_cache else ()
         return xc + m, (kv_out, aux)
 
-    x, (kvs, auxs) = cm.layer_scan(cfg, body, x, params["layers"])
+    x, (kvs, auxs) = cm.layer_scan(cfg, body, x, (layers, kinds))
     # serving only needs the last position's next-token distribution; the
     # full (B, S, V) logits tensor (and its vocab-sharded collectives) is a
     # training-only cost.  See EXPERIMENTS.md SPerf hillclimb A.
     logits = _logits(cfg, params, x[:, -1:] if last_logits else x)
-    extras = {"aux_loss": jnp.sum(auxs), "hidden": x}
+    if return_cache:
+        extras = {"aux_loss": jnp.zeros((), jnp.float32), "hidden": x,
+                  "moe": _moe_counts(auxs)}
+    else:
+        extras = {"aux_loss": jnp.sum(auxs), "hidden": x}
 
     if not return_cache:
         return logits, extras, None
@@ -166,19 +232,24 @@ def verify(cfg, params, cache: Cache, tree_tokens, tree_depth, tree_mask,
     paged = isinstance(kv, PagedKVCache)
     table = kv.block_table if paged else None
     quantized = paged and kv.scale_k is not None
+    layers, stacks = _serve_layers(cfg, params["layers"])
 
     def body(xc, xs):
-        lp, ck, cv = xs[0], xs[1], xs[2]
-        sk, sv = (xs[3], xs[4]) if len(xs) == 5 else (None, None)
+        lp, kind, kvl = xs
+        ck, cv = kvl[0], kvl[1]
+        sk, sv = (kvl[2], kvl[3]) if len(kvl) == 4 else (None, None)
+        win, rope = _kind_args(kind, kv.window)
         a, (k1, v1) = attn_verify(
             cfg, lp["attn"], cm.rmsnorm(xc, lp["ln1"], cfg.rmsnorm_eps),
             ck=ck, cv=cv, key_pos=kv.key_pos, pos=kv.pos,
             tree_depth=tree_depth, tree_mask=tree_mask,
-            window=kv.window, backend=backend, block_table=table,
-            scale_k=sk, scale_v=sv, tree_kernel=tree_kernel)
+            window=win, backend=backend, block_table=table,
+            scale_k=sk, scale_v=sv, tree_kernel=tree_kernel, rope=rope)
         xc = xc + a
-        m, _ = _mix(cfg, lp, cm.rmsnorm(xc, lp["ln2"], cfg.rmsnorm_eps))
-        return xc + m, (k1, v1)
+        m, counts = _serve_mix(cfg, lp,
+                               cm.rmsnorm(xc, lp["ln2"], cfg.rmsnorm_eps),
+                               stacks)
+        return xc + m, ((k1, v1), counts)
 
     if paged:
         kv_scan = (kv.pool_k, kv.pool_v)
@@ -186,9 +257,10 @@ def verify(cfg, params, cache: Cache, tree_tokens, tree_depth, tree_mask,
             kv_scan += (kv.scale_k, kv.scale_v)
     else:
         kv_scan = (kv.k, kv.v)
-    x, (k_new, v_new) = cm.layer_scan(cfg, body, x,
-                                  (params["layers"],) + kv_scan)
-    extras = {"tree_kv": (k_new, v_new), "hidden": x}
+    x, ((k_new, v_new), counts) = cm.layer_scan(
+        cfg, body, x, (layers, _layer_kinds(cfg), kv_scan))
+    extras = {"tree_kv": (k_new, v_new), "hidden": x,
+              "moe": _moe_counts(counts)}
     return _logits(cfg, params, x), extras
 
 

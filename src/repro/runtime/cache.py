@@ -892,15 +892,25 @@ def capacity_left(cache: Cache) -> jax.Array:
     return jnp.int32(kv.max_len) - kv.pos
 
 
+def in_window(ok, key_pos, q_pos, window):
+    """``ok`` narrowed to keys inside the window (``key_pos > q_pos -
+    window``).  ``window`` is a Python int, 0 = full attention (``ok``
+    comes back as it was), or a traced per-layer scalar of a stack with
+    ``layer_types`` (0 on its full-attention layers)."""
+    if isinstance(window, jax.Array):
+        return ok & ((window <= 0) | (key_pos > q_pos - window))
+    if window:
+        return ok & (key_pos > q_pos - window)
+    return ok
+
+
 def decode_mask(key_pos, q_pos, window):
     """Validity mask (T,) for one query at absolute position q_pos.
 
     key_pos: (T,) absolute positions in one sequence's cache (-1 empty).
     """
     ok = (key_pos >= 0) & (key_pos <= q_pos)
-    if window:
-        ok &= key_pos > q_pos - window
-    return ok
+    return in_window(ok, key_pos, q_pos, window)
 
 
 def batched_decode_mask(key_pos, q_pos, window):
@@ -912,16 +922,11 @@ def batched_decode_mask(key_pos, q_pos, window):
     kp = key_pos[:, None, :]                             # (B, 1, S)
     qp = q_pos[:, :, None]                               # (B, W, 1)
     ok = (kp >= 0) & (kp <= qp)
-    if window:
-        ok &= kp > qp - window
-    return ok
+    return in_window(ok, kp, qp, window)
 
 
 def prefill_mask(seq_len, window, q_offset=0, dtype=bool):
     """Causal (optionally windowed) (S, S) mask for prefill."""
     q = jnp.arange(seq_len)[:, None] + q_offset
     k = jnp.arange(seq_len)[None, :] + q_offset
-    m = k <= q
-    if window:
-        m &= k > q - window
-    return m
+    return in_window(k <= q, k, q, window)

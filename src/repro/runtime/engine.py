@@ -78,12 +78,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.speculative.tree import Tree, TreeSpec, chain_spec
-from repro.core.speculative.verify import SpecState, spec_prefill, spec_step
+from repro.core.speculative.verify import SpecState, spec_step
 from repro.runtime.cache import (PageAllocator, blank_paged_rows,
                                  capacity_left, insert_rows, pages_for,
                                  paginate_cache, reset_rows, slice_row,
                                  tile_rows, write_row_at)
 from repro.runtime.sampling import greedy
+from repro.runtime.telemetry import ExpertCounters
 
 _NO_EOS = -1          # sentinel: no real token id is negative
 
@@ -200,15 +201,14 @@ class DecodeStrategy:
 # every insert/reset/admit path below handles both drafts with one body.
 
 def _prefill_state(model, params, heads, batch, *, max_len, window):
-    """Prefill -> engine state.  ``heads is None`` selects the draft-free
-    path (no hidden carry)."""
-    if heads is None:
-        logits, _, cache = model.prefill(params, batch, max_len=max_len,
-                                         window=window)
-        return SpecState(cache=cache, cur_token=greedy(logits[:, -1]),
-                         hidden=None)
-    return spec_prefill(model, params, heads, batch, max_len=max_len,
-                        window=window)
+    """Prefill -> (engine state, expert counts of a sparse-expert model or
+    None).  ``heads is None`` selects the draft-free path (no hidden
+    carry)."""
+    logits, extras, cache = model.prefill(params, batch, max_len=max_len,
+                                          window=window)
+    hidden = None if heads is None else extras["hidden"][:, -1]
+    return (SpecState(cache=cache, cur_token=greedy(logits[:, -1]),
+                      hidden=hidden), extras.get("moe"))
 
 
 def _insert_row(state, b, row, pages=None):
@@ -222,14 +222,15 @@ def _insert_row(state, b, row, pages=None):
 
 
 def _admit_row(model, params, heads, state, b, batch, *, max_len, window):
-    row = _prefill_state(model, params, heads, batch, max_len=max_len,
-                         window=window)
-    return _insert_row(state, b, row), row.cur_token[0]
+    row, counts = _prefill_state(model, params, heads, batch,
+                                 max_len=max_len, window=window)
+    return _insert_row(state, b, row), row.cur_token[0], counts
 
 
 def _admit_row_paged(model, params, heads, state, b, batch, pages):
-    row = _prefill_state(model, params, heads, batch, max_len=1, window=0)
-    return _insert_row(state, b, row, pages=pages), row.cur_token[0]
+    row, counts = _prefill_state(model, params, heads, batch, max_len=1,
+                                 window=0)
+    return _insert_row(state, b, row, pages=pages), row.cur_token[0], counts
 
 
 def _reset_state_rows(state, mask):
@@ -253,7 +254,8 @@ def _extend_row(model, params, state, b, tokens, n_valid, tree):
     row's offset, ref numerics) against row ``b``'s cache view and splice
     the piece's KVs in.  The drafting carry (``cur_token``/``hidden`` when
     present) tracks the last REAL position, so the final piece leaves the
-    row exactly as a whole-prompt admission would."""
+    row exactly as a whole-prompt admission would.  Returns (state, last
+    token, expert counts or None)."""
     row_view = slice_row(state.cache, b)
     logits, extras = model.verify(params, row_view, tokens, tree,
                                   backend="ref")
@@ -265,7 +267,7 @@ def _extend_row(model, params, state, b, tokens, n_valid, tree):
         jnp.take(extras["hidden"][0], n_valid - 1, axis=0))
     return type(state)(cache=cache,
                        cur_token=state.cur_token.at[b].set(last),
-                       hidden=hid), last
+                       hidden=hid), last, extras.get("moe")
 
 
 def _seq_step(model, params, state, *, backend, active):
@@ -357,9 +359,11 @@ class _PagedPoolMixin:
 
     def _paged_init(self, *, paged, page_size, pool_pages):
         if paged and self.window:
-            raise ValueError("paged KV supports full attention only "
-                             "(sliding windows stay dense: the ring IS the "
-                             "window)")
+            raise ValueError("paged KV takes no engine-wide window (a "
+                             "windowed stack serves from the dense ring, "
+                             "which IS the window); per-layer windows "
+                             "(ModelConfig.layer_types) are masked in the "
+                             "paged path itself")
         self.paged, self.page_size = paged, page_size
         self.pool_pages = pool_pages
         self.max_pages = pages_for(self.max_len, page_size) if paged else 0
@@ -401,10 +405,13 @@ class _PagedPoolMixin:
     @property
     def sched_chunked_ok(self) -> bool:
         """Whether this engine supports chunked prefill (piecewise
-        ``sched_extend`` admission): attention-only families with full
-        attention.  Recurrent families (Mamba/xLSTM/hybrid) prefill their
-        state sequentially and stay on whole-prompt admission; sliding
-        windows stay dense/whole for the same reason the paged path does."""
+        ``sched_extend`` admission): attention-only families without an
+        engine-wide window.  Recurrent families (Mamba/xLSTM/hybrid)
+        prefill their state sequentially and stay on whole-prompt
+        admission; an engine-wide window keeps its ring (a piece would
+        have to wrap it) and so whole-prompt admission too.  Per-layer
+        windows (``layer_types``) mask each piece like any query and keep
+        chunked prefill on."""
         return self.window == 0 and \
             getattr(self.model, "family", "") in ("dense", "moe", "vlm")
 
@@ -497,9 +504,17 @@ class _PagedPoolMixin:
         slot is indistinguishable from a whole-prompt admission).  Compiled
         once per piece width C."""
         self._touch_bank()
-        return self._extend_fn(int(tokens.shape[1]))(
+        state, last, counts = self._extend_fn(int(tokens.shape[1]))(
             self.params, state, jnp.asarray(b, jnp.int32),
             jnp.asarray(tokens, jnp.int32), jnp.asarray(n_valid, jnp.int32))
+        self._count("prefill", counts)
+        return state, last
+
+    def _count(self, phase, counts) -> None:
+        """Hand a call's expert counts (still on the device) over to
+        ``self.experts``."""
+        if self.experts is not None:
+            self.experts.add(phase, counts)
 
 
 class DecodeEngine(_PagedPoolMixin):
@@ -584,8 +599,16 @@ class DecodeEngine(_PagedPoolMixin):
         self._bank_epoch = 0
         self._registered: Dict[int, DecodeStrategy] = {}
         self._registered_depth = 0
+        if window and getattr(model.cfg, "layer_types", None) is not None:
+            raise ValueError("a stack with per-layer windows "
+                             "(ModelConfig.layer_types) takes no "
+                             "engine-wide window: pass window=0")
         self.max_len, self.window = max_len, window
         self.backend, self.chunk = _attn_backend(backend), chunk
+        # routed-expert work of a sparse-expert model (stats["moe"])
+        n_exp = getattr(model.cfg, "num_experts", 0)
+        self.experts = ExpertCounters(model.cfg.num_layers) if n_exp \
+            else None
         self._paged_init(paged=paged, page_size=page_size,
                          pool_pages=pool_pages)
         # every jit target below is a NAMED def (not a lambda): the
@@ -734,9 +757,10 @@ class DecodeEngine(_PagedPoolMixin):
         outputs (greedy verification commits the greedy chain whatever
         the draft's placement or timing)."""
         if self.hcmp == "overlap" and strategy.draft == "medusa":
+            # the overlap pipeline keeps no expert counts
             return self._hcmp().run_chunk(self.params, strategy, state,
                                           done, rem, K, eos_val,
-                                          self._bank_epoch)
+                                          self._bank_epoch) + (None,)
         return self._chunk_fn(K)(self.params, self.heads, strategy, state,
                                  done, rem, eos_val)
 
@@ -782,12 +806,12 @@ class DecodeEngine(_PagedPoolMixin):
                         state, emitted, n = _seq_step(model, p, state,
                                                       backend=backend,
                                                       active=active)
+                        counts = None
                     else:
-                        state, emitted, n = spec_step(model, p, h,
-                                                      strat.tree, state,
-                                                      backend=backend,
-                                                      tree_kernel=tree_kernel,
-                                                      active=active)
+                        state, emitted, n, counts = spec_step(
+                            model, p, h, strat.tree, state, backend=backend,
+                            tree_kernel=tree_kernel, active=active,
+                            return_counts=True)
                     idx = jnp.arange(emitted.shape[1])[None, :]
                     valid = idx < n[:, None]
                     is_eos = valid & (emitted == eos)
@@ -799,12 +823,13 @@ class DecodeEngine(_PagedPoolMixin):
                     emitted = jnp.where(idx < n_eff[:, None], emitted, eos)
                     done = done | has_eos
                     rem = rem - n_eff
-                    return (state, done, rem), (emitted, n_eff)
+                    return (state, done, rem), (emitted, n_eff, counts)
 
-                (state, done, rem), (toks, ns) = jax.lax.scan(
+                (state, done, rem), (toks, ns, counts) = jax.lax.scan(
                     body, (state, done, rem), None, length=K)
-                # toks: (K, B, Dmax) eos-padded; ns: (K, B) accepted counts
-                return state, done, rem, toks, ns
+                # toks: (K, B, Dmax) eos-padded; ns: (K, B) accepted counts;
+                # counts: (K, 3) expert counts per step (None: no experts)
+                return state, done, rem, toks, ns, counts
 
             # donate the scan carry (state incl. the KV pool, done, rem):
             # in-place chunk updates, no per-chunk cache copy
@@ -817,7 +842,7 @@ class DecodeEngine(_PagedPoolMixin):
             kvdt = self.kv_dtype
 
             def prefill_paged(p, h, b, tables):
-                st = _prefill_state(model, p, h, b, max_len=1, window=0)
+                st, _ = _prefill_state(model, p, h, b, max_len=1, window=0)
                 return type(st)(
                     cache=paginate_cache(st.cache, tables, page_size=ps,
                                          n_pages=n_total, kv_dtype=kvdt),
@@ -845,7 +870,7 @@ class DecodeEngine(_PagedPoolMixin):
             state = self._prefill_paged_fn(n_total)(
                 self.params, self.heads, batch, tables)
         else:
-            state = self._prefill(self.params, self.heads, batch)
+            state, _ = self._prefill(self.params, self.heads, batch)
         n_max = int(budget.max())
         # prologue sync: materialize the prefill's first token + done mask
         # reprolint: disable=R3 (intended post-prefill sync)
@@ -862,7 +887,7 @@ class DecodeEngine(_PagedPoolMixin):
             # budget bounds the steps still needed — no full-K tail chunks
             need = int(rem_np[~done_np & (rem_np > 0)].max())
             t0 = time.perf_counter()
-            state, done, rem, toks, ns = self._run_chunk(
+            state, done, rem, toks, ns, _ = self._run_chunk(
                 _pow2_chunk(K, need), self.strategy, state, done, rem,
                 eos_val)
             # ONE host sync per chunk: this block is the whole budget
@@ -932,7 +957,7 @@ class DecodeEngine(_PagedPoolMixin):
                 state = self._prefill_paged_fn(n_total)(
                     self.params, self.heads, bd, tables)
             else:
-                state = self._prefill(self.params, self.heads, bd)
+                state, _ = self._prefill(self.params, self.heads, bd)
             done = jnp.zeros((batch,), bool)
             rem = jnp.full((batch,), 1 << 30, jnp.int32)
             eos = _eos_scalar(None)
@@ -942,13 +967,13 @@ class DecodeEngine(_PagedPoolMixin):
 
             # warm-up compiles; the donated carry is rebound from the
             # outputs
-            state, done, rem, toks, _ = step(state, done, rem)
+            state, done, rem, toks, *_ = step(state, done, rem)
             # reprolint: disable=R3 (timing harness)
             jax.block_until_ready(toks)
             best = float("inf")
             for _ in range(reps):
                 t0 = time.perf_counter()
-                state, done, rem, toks, _ = step(state, done, rem)
+                state, done, rem, toks, *_ = step(state, done, rem)
                 # this IS the measurement: ARCA times the compiled step
                 # reprolint: disable=R3 (timing harness)
                 jax.block_until_ready(toks)
@@ -964,9 +989,10 @@ class DecodeEngine(_PagedPoolMixin):
     def sched_prefill(self, batch):
         """B=1 prefill -> opaque row state.  Paged engines prefill at
         prompt size (the dense row is a splice source, not a resident)."""
-        if self.paged:
-            return self._prefill_prompt(self.params, self.heads, batch)
-        return self._prefill(self.params, self.heads, batch)
+        fn = self._prefill_prompt if self.paged else self._prefill
+        row, counts = fn(self.params, self.heads, batch)
+        self._count("prefill", counts)
+        return row
 
     @staticmethod
     def sched_first(row):
@@ -1010,10 +1036,15 @@ class DecodeEngine(_PagedPoolMixin):
             plen = reserve_len if reserve_len is not None \
                 else _prompt_len(batch)
             pages = self._sched_pages(b, plen, n_tokens)
-            return self._admit_paged(self.params, self.heads, state,
-                                     jnp.asarray(b, jnp.int32), batch, pages)
-        return self._admit(self.params, self.heads, state,
-                           jnp.asarray(b, jnp.int32), batch)
+            state, first, counts = self._admit_paged(
+                self.params, self.heads, state, jnp.asarray(b, jnp.int32),
+                batch, pages)
+        else:
+            state, first, counts = self._admit(
+                self.params, self.heads, state, jnp.asarray(b, jnp.int32),
+                batch)
+        self._count("prefill", counts)
+        return state, first
 
     def sched_reset(self, state, b):
         self._touch_bank()
@@ -1025,15 +1056,19 @@ class DecodeEngine(_PagedPoolMixin):
         # eos arrives as a Python int from the scheduler but as an int32
         # array from generate(); coerce so both paths key the SAME
         # compile-cache entry of the chunk fn (R7 retrace audit)
-        state, done, rem, toks, ns = self._run_chunk(
+        state, done, rem, toks, ns, counts = self._run_chunk(
             K, self.strategy, state, done, rem,
             jnp.asarray(eos_val, jnp.int32))
+        self._count("decode", counts)
         return state, done, rem, (toks, ns)
 
-    @staticmethod
-    def sched_fetch(raw):
+    def sched_fetch(self, raw):
         """The chunk's ``(toks, ns)`` copied to the host, after the
-        boundary's ``done``/``rem`` sync has waited out the chunk."""
+        boundary's ``done``/``rem`` sync has waited out the chunk; the
+        expert counts handed over since the last fetch (the chunk's and
+        the admissions' before it, all finished) are read with it."""
+        if self.experts is not None:
+            self.experts.fold()
         return jax.device_get(raw)
 
     @staticmethod

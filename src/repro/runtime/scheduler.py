@@ -675,6 +675,9 @@ class ContinuousScheduler:
         self._boundary_i = 0
         self._dirty = set()               # evicted rows not yet reset
         self.telemetry.reset()
+        experts = getattr(self.engine, "experts", None)
+        if experts is not None:
+            experts.reset()
         self._t0 = time.perf_counter()
 
     def submit(self, request: Request) -> None:
@@ -1063,6 +1066,11 @@ class ContinuousScheduler:
         hcmp = getattr(self.engine, "hcmp_stats", None)
         if hcmp is not None:
             stats["hcmp"] = hcmp
+        # routed-expert work of a sparse-expert model, decode and prefill
+        # (runtime/telemetry.ExpertCounters)
+        experts = getattr(self.engine, "experts", None)
+        if experts is not None:
+            stats["moe"] = experts.snapshot()
         return ordered, stats
 
     def serve(self, requests: Sequence[Request], *, eos: Optional[int] = None

@@ -24,6 +24,15 @@ and keeps the scheduler's counters:
 One ``Telemetry`` belongs to one scheduler (router replicas are threads,
 each with its own) and its memory is bounded: counters per span name and
 a histogram of fixed size.
+
+``ExpertCounters`` keeps the routed-expert work of one engine serving a
+sparse-expert model (``stats["moe"]``), by phase: ``decode`` (verify
+steps of the chunk scans) and ``prefill`` (admissions and prefill
+pieces).  Each compiled call returns its counts as a small int array
+(steps, picks, distinct experts touched, the last two summed over
+layers); the engine hands them over still on the device and they are
+read at the scheduler's next fetch of a finished chunk, whose wait they
+have already passed, so counting adds no host sync.
 """
 from __future__ import annotations
 
@@ -33,6 +42,7 @@ import time
 from typing import Dict, Optional
 
 import jax
+import numpy as np
 
 _annotation = jax.profiler.TraceAnnotation
 _clock = time.perf_counter
@@ -165,3 +175,44 @@ class Telemetry:
             "admitted": self.admitted,
             "turnover_host_s": self.turnover_host_s,
         }
+
+
+class ExpertCounters:
+    """Routed-expert work of one engine, per phase: ``steps`` (forward
+    calls), ``picks`` (token-expert picks computed) and
+    ``experts_touched`` (distinct experts read), the last two summed over
+    layers and steps; ``layers`` to divide by.  ``add`` takes a call's
+    device counts, ``fold`` reads the pending ones (after their call has
+    finished), ``snapshot`` folds and returns plain numbers."""
+
+    PHASES = ("decode", "prefill")
+    FIELDS = ("steps", "picks", "experts_touched")
+
+    def __init__(self, layers: int):
+        self.layers = layers
+        self.reset()
+
+    def reset(self) -> None:
+        self._pending = {p: [] for p in self.PHASES}
+        self._sum = {p: np.zeros((3,), np.int64) for p in self.PHASES}
+
+    def add(self, phase: str, counts) -> None:
+        """``counts``: int array (..., 3) of (steps, picks, touched), one
+        row per forward (the chunk scan's is (K, 3))."""
+        if counts is not None:
+            self._pending[phase].append(counts)
+
+    def fold(self) -> None:
+        for p in self.PHASES:
+            if self._pending[p]:
+                for c in jax.device_get(self._pending[p]):
+                    self._sum[p] += np.asarray(c, np.int64).reshape(
+                        -1, 3).sum(axis=0)
+                self._pending[p] = []
+
+    def snapshot(self) -> dict:
+        self.fold()
+        out = {"layers": self.layers}
+        for p in self.PHASES:
+            out[p] = {f: int(v) for f, v in zip(self.FIELDS, self._sum[p])}
+        return out

@@ -6,8 +6,9 @@ installed here, checks both.  Each test compiles one kernel entry point
 for one chip of a described ``v5e:2x2`` topology, at the head shapes of
 Qwen2-0.5B (Hkv=2, hd=64) and of Vicuna-7B (Hkv=32, hd=128), and asserts
 that the program holds a Mosaic kernel.  The Medusa draft is compiled at
-Qwen2-0.5B's vocabulary to check that no sort spans a whole row.  Nothing
-runs on a chip.
+Qwen2-0.5B's vocabulary to check that no sort spans a whole row; the
+expert layer's grouped matmul and the windowed paged verify at
+Mellum2-12B-A2.5B's widths.  Nothing runs on a chip.
 
 The topology is described inside a fixture, never while the module is
 imported: only one process at a time may load the TPU library.
@@ -125,3 +126,72 @@ def test_draft_compiles_without_a_full_vocabulary_sort(one_chip):
     widths = {int(w) for line in text.splitlines() if " sort(" in line
               for w in re.findall(r"\[1,4,(\d+)\]", line.split(" sort(")[0])}
     assert sorted(widths) == [10, 1_187, 1_280]
+
+
+@pytest.mark.parametrize("rows", [128, 1024], ids=["verify", "piece"])
+@pytest.mark.parametrize("kn", [(2304, 896), (896, 2304)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch, rows, kn):
+    """The expert layer's grouped matmul at Mellum2's widths (64 experts of
+    896 over d_model 2,304, 8 layers stacked): a 16-token tree's 128 picks
+    and a 128-token piece's 1,024, each m-tile visiting a whole expert
+    matrix read from the stack in place."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(ops, "_expert_matmul_impl", lambda: "pallas")
+    k, n = kn
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _compile(ops.grouped_matmul, s((rows, k), jnp.bfloat16),
+             s((8, 64, k, n), jnp.bfloat16), s((64,), jnp.int32),
+             s((), jnp.int32))
+
+
+def test_paged_verify_with_a_layer_window_compiles_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The paged tree verify masked by a traced per-layer window, at
+    Mellum2's heads (32 query, 4 KV, 128 wide)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    W, Hq, Hkv, hd = 16, 32, 4, 128
+    pool = s((N_PAGES + 1, PS, Hkv, hd), jnp.bfloat16)
+    _compile(lambda q, pk, pv, kn, vn, t, kp, pos, d, m, win:
+             ops.paged_tree_attention(q, pk, pv, kn, vn, t, kp, pos, d, m,
+                                      window=win),
+             s((1, W, Hq, hd), jnp.bfloat16), pool, pool,
+             s((1, W, Hkv, hd), jnp.bfloat16), s((1, W, Hkv, hd),
+                                                 jnp.bfloat16),
+             s((1, MAXP), jnp.int32), s((1, MAXP * PS), jnp.int32),
+             s((1,), jnp.int32), s((W,), jnp.int32),
+             s((W, W), jnp.bool_), s((), jnp.int32))
+
+
+def test_grouped_matmul_reads_a_layer_scan_stack_in_place(one_chip,
+                                                        monkeypatch):
+    """Inside a layer scan the grouped matmul reads the layer's experts
+    from the whole (L, E, k, n) stack: no (E, k, n) slice of it is made
+    (a scanned slice would copy every expert of the layer each step)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(ops, "_expert_matmul_impl", lambda: "pallas")
+    L, E, k, n = 4, 8, 256, 128
+
+    def stack_scan(x, w, sizes):
+        def body(h, layer):
+            return ops.grouped_matmul(h, w, sizes, layer)[:, :k], None
+        return jax.lax.scan(body, x, jnp.arange(L))[0]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = s((L, E, k, k), jnp.bfloat16)
+    text = jax.jit(stack_scan).lower(s((128, k), jnp.bfloat16), w,
+                                     s((E,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"= bf16\[{E},{k},{k}\]", text)

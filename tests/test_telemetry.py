@@ -221,3 +221,23 @@ def test_decode_chunk_ops_carry_the_step_phases():
     for phase in ("draft", "verify", "commit"):
         assert any(n.startswith("jit(chunk_scan)/") and f"/{phase}/" in n
                    for n in names), phase
+
+
+def test_expert_counters_fold_by_phase():
+    """Counts handed over as device arrays, one row per forward, are summed
+    per phase when folded; ``reset`` starts a stream at zero."""
+    c = telemetry.ExpertCounters(layers=8)
+    c.add("decode", jnp.asarray([[1, 1024, 400], [1, 1024, 420]], jnp.int32))
+    c.add("prefill", jnp.asarray([1, 8192, 512], jnp.int32))
+    c.add("decode", None)                       # a call without experts
+    snap = c.snapshot()
+    assert snap == {"layers": 8,
+                    "decode": {"steps": 2, "picks": 2048,
+                               "experts_touched": 820},
+                    "prefill": {"steps": 1, "picks": 8192,
+                                "experts_touched": 512}}
+    c.add("decode", jnp.asarray([[1, 1024, 410]], jnp.int32))
+    assert c.snapshot()["decode"]["steps"] == 3
+    c.reset()
+    assert c.snapshot()["decode"] == {"steps": 0, "picks": 0,
+                                      "experts_touched": 0}

@@ -67,5 +67,8 @@ def test_moe_aux_loss_nonzero():
     params = model.init_params(jax.random.PRNGKey(0))
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (2, 16)), jnp.int32)
-    _, extras, _ = model.prefill(params, {"tokens": toks}, max_len=16)
+    # the load-balance loss is the training forward's (serving prefill
+    # routes dropless and computes no loss)
+    _, extras, _ = model.prefill(params, {"tokens": toks},
+                                 return_cache=False)
     assert float(extras["aux_loss"]) > 0.0
